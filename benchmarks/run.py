@@ -52,6 +52,9 @@ def main(argv=None) -> int:
                     help="directory for BENCH_*.json trajectory files")
     args = ap.parse_args(argv)
 
+    from repro.runtime.compile_cache import use_compile_cache
+    use_compile_cache()
+
     from . import (bench_inference, bench_launch_order, bench_overhead,
                    bench_scheduler, bench_throughput, bench_utilization,
                    bench_wallclock)

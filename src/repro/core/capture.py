@@ -41,6 +41,7 @@ Execution semantics are unchanged from the wave model:
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Any, Callable, Mapping, Sequence
 
@@ -581,23 +582,27 @@ def capture(
     output_slots = [slot_of[o] for o in output_ids]
     tree_map = jax.tree_util.tree_map
 
-    def run(*args: Any) -> list[Any]:
+    # Weights enter the program as arguments, not closure constants: a
+    # closed-over array is embedded in the HLO as a literal, which at model
+    # scale (~1 GB of bf16 weights) bloats compilation past usefulness.
+    step_consts = [step.consts for step in steps]
+
+    def run(consts: Sequence[tuple], *args: Any) -> list[Any]:
         env: list[Any] = [None] * n_slots
         for s, a in zip(input_slots, args):
             env[s] = a
-        for step in steps:
+        for step, cs in zip(steps, consts):
             if step.route == _CALL:
-                out = step.fn(*[env[s] for s in step.arg_slots], *step.consts)
+                out = step.fn(*[env[s] for s in step.arg_slots], *cs)
                 env[step.out_slots[0]] = out
             elif step.route == _GROUPED_GEMM:
-                outs = step.fn([env[s] for s in step.arg_slots[0]],
-                               *step.consts)
+                outs = step.fn([env[s] for s in step.arg_slots[0]], *cs)
                 for k, slot in enumerate(step.out_slots):
                     env[slot] = outs[k]
             else:
                 stacked = [jnp.stack([env[s] for s in slots])
                            for slots in step.arg_slots]
-                outs = step.fn(*stacked, *step.consts)
+                outs = step.fn(*stacked, *cs)
                 for k, slot in enumerate(step.out_slots):
                     env[slot] = tree_map(lambda x: x[k], outs)
             for s in step.free_slots:
@@ -606,14 +611,14 @@ def capture(
 
     jit_kwargs: dict[str, Any] = {}
     if donate_inputs:
-        jit_kwargs["donate_argnums"] = tuple(range(len(input_ids)))
+        jit_kwargs["donate_argnums"] = tuple(range(1, len(input_ids) + 1))
     return CapturedGraph(
         graph=graph,
         schedule=schedule,
         input_ids=input_ids,
         output_ids=output_ids,
-        fn=run,
-        jitted=jax.jit(run, **jit_kwargs),
+        fn=functools.partial(run, step_consts),
+        jitted=functools.partial(jax.jit(run, **jit_kwargs), step_consts),
         steps=steps,
         degradations=deg_log,
     )

@@ -25,9 +25,10 @@ cached and re-used ("profile once", then amortize):
   state (and handing back the table).
 
 The calibration cache on :class:`repro.core.Session` keys tables by
-``(graph.node_signature(), graph.input_signature(inputs), hw.name)``: the
-structural graph shape, the input shapes/dtypes the profiling run saw, and
-the hardware the timings are valid for.  A structurally identical graph
+``(graph.node_signature(), graph.input_signature(inputs), platform,
+device_kind)``: the structural graph shape, the input shapes/dtypes the
+profiling run saw, and the device that did the timing (as JAX reports it),
+so a CPU timing never hydrates a TPU plan.  A structurally identical graph
 (e.g. a reloaded checkpoint) hydrates from the cache instead of re-timing.
 ``profile_measured`` remains as the one-call convenience (measure + apply).
 
@@ -79,6 +80,28 @@ class HardwareSpec:
 
 V5E = HardwareSpec()
 
+# One peak table keyed by ``device_kind`` as JAX reports it.  Peaks of one
+# v5e chip: Google Cloud documentation, "TPU v5e".
+HARDWARE_BY_DEVICE_KIND: dict[str, HardwareSpec] = {"TPU v5 lite": V5E}
+
+
+def hardware_for(device_kind: str) -> HardwareSpec:
+    """The :class:`HardwareSpec` of a device kind; an unknown kind is an
+    error, never a silent v5e."""
+    try:
+        return HARDWARE_BY_DEVICE_KIND[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no HardwareSpec for device_kind {device_kind!r}; known: "
+            f"{sorted(HARDWARE_BY_DEVICE_KIND)}") from None
+
+
+def measuring_device() -> str:
+    """``platform:device_kind`` of the device profiling runs on (JAX's
+    default device) — the identity measured timings are filed under."""
+    d = jax.devices()[0]
+    return f"{d.platform}:{d.device_kind}"
+
 
 @dataclasses.dataclass
 class OpProfile:
@@ -99,19 +122,19 @@ class ProfileTable:
     ``fingerprint`` as a plan-cache key component.
     """
 
-    hw_name: str
+    device: str                                 # measuring_device() of the run
     measured_us: tuple[tuple[int, float], ...]  # (op_id, wall µs), sorted
 
     @functools.cached_property
     def fingerprint(self) -> tuple:
-        """Compact identity: (hw_name, sha1-of-timings, n).  Plan/executable
+        """Compact identity: (device, sha1-of-timings, n).  Plan/executable
         cache keys embed this for every calibrated graph, so it must stay
         O(1) to hash — a raw per-op timing tuple would put O(n) floats back
         into every warm-path cache probe."""
         import hashlib
 
         digest = hashlib.sha1(repr(self.measured_us).encode()).hexdigest()
-        return (self.hw_name, digest, len(self.measured_us))
+        return (self.device, digest, len(self.measured_us))
 
     def as_dict(self) -> dict[int, float]:
         return dict(self.measured_us)
@@ -138,8 +161,8 @@ def detach_profile(graph: OpGraph) -> ProfileTable | None:
     graph.calibration_fp = None
     if not measured:
         return None
-    hw_name = fp[0] if fp else ""
-    return ProfileTable(hw_name=hw_name, measured_us=measured)
+    device = fp[0] if fp else ""
+    return ProfileTable(device=device, measured_us=measured)
 
 
 # Operator kinds that engage the MXU / systolic pipeline — the paper's
@@ -218,7 +241,8 @@ class ModelProfiler:
             dt = (time.perf_counter() - t0) / repeats * 1e6
             measured.append((i, dt))
             values[i] = out
-        return ProfileTable(hw_name=self.hw.name, measured_us=tuple(measured))
+        return ProfileTable(device=measuring_device(),
+                            measured_us=tuple(measured))
 
     def profile_measured(
         self,

@@ -33,7 +33,8 @@ Cache semantics are unchanged from the module-global era — see the table in
   different graph object is rebound (op_ids are structural).
 * **executable** — plan key + a weights fingerprint (``identity`` or
   ``content``) + output ids + kernel route.
-* **calibration** — (node_signature, input_signature, hw.name), memory LRU
+* **calibration** — (node_signature, input_signature, measuring device),
+  memory LRU
   over a JSON disk tier under ``SessionConfig.calib_dir`` (default
   ``$REPRO_CALIB_DIR`` or ``~/.cache/repro/calib``).
 """
@@ -62,6 +63,7 @@ from .profiler import (
     ProfileTable,
     V5E,
     apply_profile,
+    measuring_device,
 )
 from .scheduler import (
     ALLOC_POLICIES,
@@ -187,9 +189,13 @@ def graph_signature(
 
 
 def calibration_key(graph: OpGraph, inputs: Mapping[int, Any],
-                    hw: HardwareSpec = V5E) -> tuple:
-    """Calibration-cache key: structure × input geometry × hardware."""
-    return (graph.node_signature(), graph.input_signature(inputs), hw.name)
+                    device: str | None = None) -> tuple:
+    """Calibration-cache key: structure × input geometry × the device that
+    times it (``platform:device_kind``, default: :func:`measuring_device`).
+    The session's cost-model ``HardwareSpec`` is deliberately absent: it
+    names no device, so keying by it filed CPU timings as chip timings."""
+    return (graph.node_signature(), graph.input_signature(inputs),
+            device if device is not None else measuring_device())
 
 
 def _content_digest(a: Any) -> tuple:
@@ -299,7 +305,7 @@ def _calib_disk_load(key: tuple, dirpath: str | None = None,
         return None               # sha1 collision / stale format / corrupt
     try:
         return ProfileTable(
-            hw_name=doc["hw_name"],
+            device=doc["device"],
             measured_us=tuple((int(i), float(us))
                               for i, us in doc["measured_us"]))
     except (KeyError, TypeError, ValueError):
@@ -320,7 +326,7 @@ def _calib_disk_store(key: tuple, table: ProfileTable,
     d = _calib_dir(dirpath)
     tmp = None
     try:
-        payload = json.dumps({"key": repr(key), "hw_name": table.hw_name,
+        payload = json.dumps({"key": repr(key), "device": table.device,
                               "measured_us": [list(m)
                                               for m in table.measured_us]})
         os.makedirs(d, exist_ok=True)
@@ -514,7 +520,7 @@ class Session:
                    load: bool | None = None) -> tuple[ProfileTable | None, str]:
         repeats = cfg.calibration_repeats if repeats is None else repeats
         load = cfg.load_calibration if load is None else load
-        key = calibration_key(graph, inputs, cfg.hw)
+        key = calibration_key(graph, inputs)
         faults = self.faults
         provenance = "memory"
         table = _lru_get(self._calib_cache, key)

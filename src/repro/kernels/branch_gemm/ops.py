@@ -9,7 +9,7 @@ import warnings
 import jax
 
 from ...runtime.faults import maybe_fire
-from ...runtime.guard import DegradationWarning
+from ...runtime.guard import DegradationWarning, kernel_log
 from .. import interpret_mode
 from .kernel import branch_gemm_pallas
 from .ref import branch_gemm_ref
@@ -48,6 +48,10 @@ def branch_gemm(x: jax.Array, w: jax.Array, bm: int = 128, bf: int = 128,
         return branch_gemm_pallas(x, w, bm=bm, bf=bf, bk=bk,
                                   interpret=interpret_mode())
     except Exception as exc:
+        # counted on the kernel ladder log (a chip run asserts it is empty)
+        # and warned on every event, not once per process
+        kernel_log().note("branch_gemm", "pallas->ref",
+                          f"Pallas launch failed: {exc!r}")
         warnings.warn(f"branch_gemm: Pallas launch failed ({exc!r}); "
                       "running the einsum reference",
                       DegradationWarning, stacklevel=2)

@@ -8,7 +8,12 @@ so ring/window policies stay outside the kernel.
 
     q: [B, H, D]   k,v: [B, KVH, T, D]   valid: [B, T]  →  out: [B, H, D]
 
-Grid: (B, H, T/bk), KV tiles innermost (sequential accumulation).
+The query is viewed as ``[B, KVH, G, D]`` (``G = H / KVH``, heads of one
+KV group adjacent) and one block carries all G query heads of a KV head:
+each KV tile is read once per KV head, and the block's last two dims equal
+the array's, which the TPU lowering requires for head counts that are not
+multiples of 8.  Grid: (B, KVH, T/bk), KV tiles innermost (sequential
+accumulation).
 """
 from __future__ import annotations
 
@@ -32,12 +37,12 @@ def _kernel(q_ref, k_ref, v_ref, valid_ref, o_ref, m_ref, l_ref, acc_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    q = q_ref[0]                                       # [1, D] row block
+    q = q_ref[0, 0]                                    # [G, D]
     k = k_ref[0, 0]                                    # [bk, D]
     v = v_ref[0, 0]
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * scale  # [1,bk]
-    valid = valid_ref[...]                             # [1, bk] int32 mask block
+                            preferred_element_type=jnp.float32) * scale  # [G,bk]
+    valid = valid_ref[0]                               # [1, bk] int32 mask
     s = jnp.where(valid > 0, s, NEG_INF)
 
     m_prev = m_ref[...]
@@ -52,7 +57,8 @@ def _kernel(q_ref, k_ref, v_ref, valid_ref, o_ref, m_ref, l_ref, acc_ref,
 
     @pl.when(kv_i == pl.num_programs(2) - 1)
     def _store():
-        o_ref[0] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
+        o_ref[0, 0] = (acc_ref[...]
+                       / jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("bk", "interpret"))
@@ -66,28 +72,27 @@ def decode_attention_pallas(
 ) -> jax.Array:
     b, h, d = q.shape
     _, kvh, t, _ = k.shape
-    groups = h // kvh
+    g = h // kvh
     bk = min(bk, t)
     assert t % bk == 0
-    grid = (b, h, t // bk)
+    grid = (b, kvh, t // bk)
     kernel = functools.partial(_kernel, scale=d ** -0.5)
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, 1, d), lambda bb, hh, kk: (bb, hh, 0)),
-            pl.BlockSpec((1, 1, bk, d),
-                         lambda bb, hh, kk, g=groups: (bb, hh // g, kk, 0)),
-            pl.BlockSpec((1, 1, bk, d),
-                         lambda bb, hh, kk, g=groups: (bb, hh // g, kk, 0)),
-            pl.BlockSpec((1, bk), lambda bb, hh, kk: (bb, kk)),
+            pl.BlockSpec((1, 1, g, d), lambda bb, hh, kk: (bb, hh, 0, 0)),
+            pl.BlockSpec((1, 1, bk, d), lambda bb, hh, kk: (bb, hh, kk, 0)),
+            pl.BlockSpec((1, 1, bk, d), lambda bb, hh, kk: (bb, hh, kk, 0)),
+            pl.BlockSpec((1, 1, bk), lambda bb, hh, kk: (bb, 0, kk)),
         ],
-        out_specs=pl.BlockSpec((1, 1, d), lambda bb, hh, kk: (bb, hh, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, h, d), q.dtype),
+        out_specs=pl.BlockSpec((1, 1, g, d), lambda bb, hh, kk: (bb, hh, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((b, kvh, g, d), q.dtype),
         scratch_shapes=[
-            pltpu.VMEM((1, 1), jnp.float32),
-            pltpu.VMEM((1, 1), jnp.float32),
-            pltpu.VMEM((1, d), jnp.float32),
+            pltpu.VMEM((g, 1), jnp.float32),
+            pltpu.VMEM((g, 1), jnp.float32),
+            pltpu.VMEM((g, d), jnp.float32),
         ],
         interpret=interpret,
-    )(q, k, v, valid.astype(jnp.int32))
+    )(q.reshape(b, kvh, g, d), k, v, valid.astype(jnp.int32)[:, None, :])
+    return out.reshape(b, h, d)

@@ -16,7 +16,7 @@ import jax
 import jax.numpy as jnp
 
 from ...runtime.faults import maybe_fire
-from ...runtime.guard import DegradationWarning
+from ...runtime.guard import DegradationWarning, kernel_log
 from .. import INTERPRET_GRID_LIMIT, interpret_mode
 from ..branch_gemm.ops import select_tiles
 from .kernel import grouped_gemm_pallas
@@ -76,6 +76,8 @@ def grouped_gemm_parts(xs: list[jax.Array], w: jax.Array,
         # Pallas launch failure (real, or injected via the
         # ``grouped_gemm_route`` site): the per-part einsum reference
         # computes the identical function
+        kernel_log().note("grouped_gemm", "pallas->ref",
+                          f"Pallas launch failed: {exc!r}")
         warnings.warn(f"grouped_gemm: Pallas launch failed ({exc!r}); "
                       "running the einsum reference",
                       DegradationWarning, stacklevel=2)

@@ -11,7 +11,11 @@ matmul runs.  Nothing is ever gathered into a contiguous slab.
     q:  [B, H, Dk]          k: [P, KVH, ps, Dk]     v: [P, KVH, ps, Dv]
     bt: [B*MAXP] int32      starts, lengths: [B] int32   →   out: [B, H, Dv]
 
-Grid: (B, H, MAXP), pages innermost (sequential accumulation).  Masking is
+The query is viewed as ``[B, KVH, G, Dk]`` (``G = H / KVH``) and one block
+carries all G query heads of a KV head, so each page is read once per KV
+head and the block's last two dims equal the array's (the TPU lowering's
+rule for head counts that are not multiples of 8).
+Grid: (B, KVH, MAXP), pages innermost (sequential accumulation).  Masking is
 positional (``starts <= pos < lengths``), so trailing table entries may
 point anywhere (the engine points them at the reserved null page 0).
 ``Dv != Dk`` is supported — the MLA absorbed variant attends latent pages
@@ -41,7 +45,7 @@ def _kernel(bt_ref, starts_ref, lengths_ref, q_ref, k_ref, v_ref, o_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    q = q_ref[0]                                       # [1, Dk] row block
+    q = q_ref[0, 0]                                    # [G, Dk]
     k = k_ref[0, 0]                                    # [ps, Dk]
     v = v_ref[0, 0]                                    # [ps, Dv]
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
@@ -63,7 +67,8 @@ def _kernel(bt_ref, starts_ref, lengths_ref, q_ref, k_ref, v_ref, o_ref,
 
     @pl.when(pi == pl.num_programs(2) - 1)
     def _store():
-        o_ref[0] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
+        o_ref[0, 0] = (acc_ref[...]
+                       / jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "interpret"))
@@ -80,34 +85,35 @@ def paged_decode_attention_pallas(
     b, h, dk = q.shape
     _, kvh, ps, _ = k.shape
     dv = v.shape[-1]
-    groups = h // kvh
+    g = h // kvh
     maxp = block_tables.shape[0] // b
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=(b, h, maxp),
+        grid=(b, kvh, maxp),
         in_specs=[
-            pl.BlockSpec((1, 1, dk),
-                         lambda bb, hh, pp, bt, st, ln: (bb, hh, 0)),
+            pl.BlockSpec((1, 1, g, dk),
+                         lambda bb, hh, pp, bt, st, ln: (bb, hh, 0, 0)),
             pl.BlockSpec((1, 1, ps, dk),
-                         lambda bb, hh, pp, bt, st, ln, g=groups, mp=maxp:
-                         (bt[bb * mp + pp], hh // g, 0, 0)),
+                         lambda bb, hh, pp, bt, st, ln, mp=maxp:
+                         (bt[bb * mp + pp], hh, 0, 0)),
             pl.BlockSpec((1, 1, ps, dv),
-                         lambda bb, hh, pp, bt, st, ln, g=groups, mp=maxp:
-                         (bt[bb * mp + pp], hh // g, 0, 0)),
+                         lambda bb, hh, pp, bt, st, ln, mp=maxp:
+                         (bt[bb * mp + pp], hh, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, dv),
-                               lambda bb, hh, pp, bt, st, ln: (bb, hh, 0)),
+        out_specs=pl.BlockSpec((1, 1, g, dv),
+                               lambda bb, hh, pp, bt, st, ln: (bb, hh, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((1, 1), jnp.float32),
-            pltpu.VMEM((1, 1), jnp.float32),
-            pltpu.VMEM((1, dv), jnp.float32),
+            pltpu.VMEM((g, 1), jnp.float32),
+            pltpu.VMEM((g, 1), jnp.float32),
+            pltpu.VMEM((g, dv), jnp.float32),
         ],
     )
     kernel = functools.partial(_kernel, scale=scale, page_size=ps)
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, h, dv), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, kvh, g, dv), q.dtype),
         interpret=interpret,
     )(block_tables.astype(jnp.int32), starts.astype(jnp.int32),
-      lengths.astype(jnp.int32), q, k, v)
+      lengths.astype(jnp.int32), q.reshape(b, kvh, g, dk), k, v)
+    return out.reshape(b, h, dv)

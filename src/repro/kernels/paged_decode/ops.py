@@ -37,7 +37,7 @@ def paged_decode_attention(q, k_pages, v_pages, block_tables, lengths,
             "gather-einsum reference")
         return paged_decode_attention_ref(q, k_pages, v_pages, block_tables,
                                           lengths, starts, scale)
-    if interpret_mode() and b * h * maxp > INTERPRET_GRID_LIMIT:
+    if interpret_mode() and b * kvh * maxp > INTERPRET_GRID_LIMIT:
         # interpret mode unrolls the grid at trace time; beyond the shared
         # limit the gather-einsum reference compiles and runs faster (same
         # silent route decision as grouped_gemm's interpret guard).

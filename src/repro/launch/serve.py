@@ -1,7 +1,12 @@
-"""End-to-end serving driver (continuous batching on a smoke model).
+"""End-to-end serving entry point (continuous batching).
 
     PYTHONPATH=src python -m repro.launch.serve --arch llama3.2-1b \
         --requests 8 --max-tokens 16
+
+The CLI serves the smoke-size configuration with the reference attention
+paths.  ``serve(..., smoke=False, use_kernels=True, paged_kv=True)`` serves
+the published widths through the Pallas kernels and the paged KV cache (the
+path ``chip_smoke.py`` checks on a TPU).
 
 Multi-tenant overload mode: ``--tenants N`` spreads the requests over N
 tenants — each with its own isolated :class:`repro.core.Session` so
@@ -13,6 +18,7 @@ trace, printing the goodput/shed/expiry ledger instead of falling over.
 from __future__ import annotations
 
 import argparse
+import copy
 import sys
 import time
 
@@ -24,14 +30,25 @@ from ..models import Model
 from ..serving import (AdmissionConfig, InferenceEngine, Request,
                        RequestState, TERMINAL_STATES)
 
+# One page is one KV tile of the paged-decode kernel, which needs a lane
+# multiple (128); the reference path reads any page size.
+PAGE_SIZE = 128
+
 
 def serve(arch: str, n_requests: int, max_tokens: int, slots: int = 4,
           max_len: int = 128, temperature: float = 0.0,
           calibrate: bool = False, tenants: int = 1,
           overload: bool = False, max_queue: int | None = None,
-          tenant_quota: int | None = None, ttl: int | None = None) -> dict:
-    cfg = get_config(arch, smoke=True)
-    model = Model(cfg)
+          tenant_quota: int | None = None, ttl: int | None = None,
+          smoke: bool = True, use_kernels: bool = False,
+          paged_kv: bool = False) -> dict:
+    """Serve ``n_requests`` seeded requests and return the ledger.
+
+    Besides the counts, the result carries the engine's ``fault_stats`` and
+    ``degradations``: the fallback events recorded on the serving and
+    tenant sessions."""
+    cfg = get_config(arch, smoke=smoke)
+    model = Model(cfg, use_kernels=use_kernels)
     params = model.init(jax.random.key(0))
     # one explicit Session for the whole serving process: every engine this
     # driver spins up shares its measured-profile / schedule caches.  Each
@@ -46,7 +63,8 @@ def serve(arch: str, n_requests: int, max_tokens: int, slots: int = 4,
     engine = InferenceEngine(model, params, max_slots=slots, max_len=max_len,
                              session=session, calibrate=calibrate,
                              admission=admission,
-                             tenant_sessions=tenant_sessions)
+                             tenant_sessions=tenant_sessions,
+                             paged_kv=paged_kv, page_size=PAGE_SIZE)
     if calibrate and engine.schedule_plan is not None:
         p = engine.schedule_plan
         stats = session.cache_stats()
@@ -89,6 +107,11 @@ def serve(arch: str, n_requests: int, max_tokens: int, slots: int = 4,
         "wall_s": wall,
         "tok_per_s": total_tokens / wall if wall > 0 else 0.0,
     }
+    ledger = {
+        "fault_stats": copy.deepcopy(engine.fault_stats),
+        "degradations": session.guard_log.as_dicts() + [
+            e for s in tenant_sessions.values() for e in s.guard_log.as_dicts()],
+    }
     for r in done[:8]:
         if r.state is RequestState.DONE:
             print(f"[serve] rid={r.rid} {r.tenant} prompt_len={len(r.prompt)} "
@@ -103,7 +126,7 @@ def serve(arch: str, n_requests: int, max_tokens: int, slots: int = 4,
             print(f"[serve] {name}: {stats} ({events} provenance events)")
         print(f"[serve] health: {engine.health()}")
     print(f"[serve] {result}")
-    return result
+    return {**result, **ledger}
 
 
 def main(argv=None) -> int:
@@ -125,6 +148,8 @@ def main(argv=None) -> int:
     ap.add_argument("--ttl", type=int, default=None,
                     help="per-request deadline in ticks from submission")
     args = ap.parse_args(argv)
+    from ..runtime.compile_cache import use_compile_cache
+    use_compile_cache()
     res = serve(args.arch, args.requests, args.max_tokens, args.slots,
                 calibrate=args.calibrate, tenants=args.tenants,
                 overload=args.overload, max_queue=args.max_queue,

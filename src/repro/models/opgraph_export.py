@@ -89,11 +89,13 @@ def build_lm_opgraph(cfg: ModelConfig, batch: int, seq: int,
     def fn_or_none(f):
         return f if params is not None else None
 
+    def table_consts(table):
+        return {"consts": (table["table"],)} if params is not None else {}
+
     root = g.add("tokens", OpKind.INPUT, out_shape=(b, s))
-    emb_w = _w(params, "embed", "table")
-    x = g.add("embed", OpKind.GATHER, [root],
-              fn=fn_or_none(lambda t: jnp.take(emb_w, t, axis=0)),
-              cost=gather_cost(b * s, d), out_shape=(b, s, d))
+    x = g.add("embed", OpKind.GATHER, [root], fn=fn_or_none(_embed_payload),
+              cost=gather_cost(b * s, d), out_shape=(b, s, d),
+              **table_consts(_w(params, "embed")))
 
     meta = stack_meta(cfg)
     layer_idx = 0
@@ -118,11 +120,20 @@ def build_lm_opgraph(cfg: ModelConfig, batch: int, seq: int,
     x = _norm_node(g, "final_norm", x, _w(params, "final_norm"), cfg.norm,
                    b * s * d)
     head = _w(params, "embed" if cfg.tie_embeddings else "head")
-    g.add("logits", OpKind.GEMM, [x],
-          fn=fn_or_none(lambda h: jnp.einsum("bsd,vd->bsv", h, head["table"])),
-          cost=gemm_cost(b * s, d, cfg.vocab_size))
+    g.add("logits", OpKind.GEMM, [x], fn=fn_or_none(_logits_payload),
+          cost=gemm_cost(b * s, d, cfg.vocab_size), **table_consts(head))
     g.validate()
     return g
+
+
+def _embed_payload(tokens, table):
+    return jnp.take(table, tokens, axis=0)
+
+
+def _logits_payload(h, table):
+    """Tied/untied head in fp32, exactly ``layers.unembed``."""
+    return jnp.einsum("bsd,vd->bsv", h, table,
+                      preferred_element_type=jnp.float32)
 
 
 def _rms(p, h, eps=1e-6):
@@ -222,6 +233,25 @@ def _scores_payload(q, k):
                       preferred_element_type=jnp.float32).reshape(b, nh, s, t)
 
 
+def _rope_head_major(x, theta: float):
+    """``apply_rope`` at positions ``0..S-1`` on a head-major [B,H,S,D]."""
+    pos = jnp.arange(x.shape[2])[None]
+    return apply_rope(x.transpose(0, 2, 1, 3), pos, theta).transpose(0, 2, 1, 3)
+
+
+@functools.lru_cache(maxsize=None)
+def _make_scores(rope_theta: float | None):
+    """Score stage; with ``rope_theta`` it first ropes q and k (prefill
+    positions), as ``gqa_prefill`` does before attending."""
+    if rope_theta is None:
+        return _scores_payload
+
+    def scores(q, k):
+        return _scores_payload(_rope_head_major(q, rope_theta),
+                               _rope_head_major(k, rope_theta))
+    return scores
+
+
 @functools.lru_cache(maxsize=None)
 def _make_scale_mask(scale: float, window: int | None, causal: bool):
     def scale_mask(x):
@@ -254,14 +284,15 @@ def _merge_heads(x):
 
 
 def _attn_core(g, pre, qt, kt, vt, b, s, t, nh, kvh, hd, dv,
-               scale, causal, window, with_fn):
+               scale, causal, window, with_fn, rope_theta=None):
     """scores → scale+mask → softmax → ctx → head-merge, from head-major
     Q/K/V nodes.  The scores/ctx pair carries exactly the 4·b·h·s·t·d
     attention FLOPs (2·m·k·n each); mask/softmax are the memory-bound
     stages the scheduler overlaps with neighboring GEMMs."""
     def F(f):
         return f if with_fn else None
-    sc = g.add(f"{pre}scores", OpKind.GEMM, [qt, kt], fn=F(_scores_payload),
+    sc = g.add(f"{pre}scores", OpKind.GEMM, [qt, kt],
+               fn=F(_make_scores(rope_theta)),
                cost=gemm_cost(b * nh * s, hd, t),
                fuse_sig=("qk", s, t, hd), out_shape=(b, nh, s, t))
     sm = g.add(f"{pre}scale_mask", OpKind.ELEMENTWISE, [sc],
@@ -281,7 +312,8 @@ def _attn_core(g, pre, qt, kt, vt, b, s, t, nh, kvh, hd, dv,
 
 
 def _attn_stages(g, pre, q, k, v, b, s, t, nh, kvh, hd,
-                 scale=None, causal=True, window=None, with_fn=False):
+                 scale=None, causal=True, window=None, with_fn=False,
+                 rope_theta=None):
     """Full decomposed attention from flat [B,S,H·D] projection outputs:
     three head-split transpose copies (the memory-intensive stage bert/t5
     already export), then :func:`_attn_core`."""
@@ -302,7 +334,7 @@ def _attn_stages(g, pre, q, k, v, b, s, t, nh, kvh, hd,
                cost=elementwise_cost(b * t * kvh * hd),
                fuse_sig=("tps", t, kvh, hd), out_shape=(b, kvh, t, hd))
     return _attn_core(g, pre, qt, kt, vt, b, s, t, nh, kvh, hd, hd,
-                      scale, causal, window, with_fn)
+                      scale, causal, window, with_fn, rope_theta)
 
 
 # -- MLA (DeepSeek-style latent attention), decomposed ------------------------
@@ -407,6 +439,10 @@ def _mla_block(g, cfg, n1, b, s, tag, attn_p):
                       b * s, nh * m.v_head_dim, d)
 
 
+def _rope_theta(cfg: ModelConfig) -> float | None:
+    return float(cfg.rope_theta) if cfg.rope else None
+
+
 def _dense_layer(g, cfg, x, b, s, tag, pl, root, moe: bool,
                  moe_branch_cap: int = 16, moe_dispatch: str = "auto",
                  moe_cap_scale: float = 1.0):
@@ -424,7 +460,8 @@ def _dense_layer(g, cfg, x, b, s, tag, pl, root, moe: bool,
         k = _gemm_node(g, f"{tag}.wk", n1, attn_p and attn_p["wk"], b * s, d, kvh * hd, bias)
         v = _gemm_node(g, f"{tag}.wv", n1, attn_p and attn_p["wv"], b * s, d, kvh * hd, bias)
         mrg = _attn_stages(g, f"{tag}.", q, k, v, b, s, s, nh, kvh, hd,
-                           causal=True, window=None, with_fn=pl is not None)
+                           causal=True, window=None, with_fn=pl is not None,
+                           rope_theta=_rope_theta(cfg))
         o = _gemm_node(g, f"{tag}.wo", mrg, attn_p and attn_p["wo"], b * s, nh * hd, d, False)
     r1 = g.add(f"{tag}.res1", OpKind.ELEMENTWISE, [x, o],
                fn=(lambda a, c: a + c) if pl else None,
@@ -742,7 +779,8 @@ def _hybrid_layer(g, cfg, x, b, s, tag, pl, window, root):
     v = _gemm_node(g, f"{tag}.wv", n1, attn_p and attn_p["wv"],
                    b * s, d, kvh * hd, cfg.qkv_bias)
     mrg = _attn_stages(g, f"{tag}.", q, k, v, b, s, s, nh, kvh, hd,
-                       causal=True, window=window, with_fn=with_fn)
+                       causal=True, window=window, with_fn=with_fn,
+                       rope_theta=_rope_theta(cfg))
     o = _gemm_node(g, f"{tag}.wo", mrg, attn_p and attn_p["wo"],
                    b * s, nh * hd, d)
     # parallel mamba branch (memory-bound scan against the MXU wave above)

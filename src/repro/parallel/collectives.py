@@ -19,8 +19,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from .compat import axis_size
-
 
 def collective_matmul(x: jax.Array, w: jax.Array, axis_name: str) -> jax.Array:
     """Ring-overlapped x_full @ w_shard inside ``shard_map``.
@@ -34,7 +32,7 @@ def collective_matmul(x: jax.Array, w: jax.Array, axis_name: str) -> jax.Array:
     i+1 is issued before the GEMM of step i consumes its operand, so XLA's
     latency-hiding scheduler overlaps ICI with MXU.
     """
-    a = axis_size(axis_name)
+    a = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     k_shard = x.shape[-1]
 
@@ -84,6 +82,6 @@ def psum_scatter_grads(grads, axis_name: str):
     return jax.tree_util.tree_map(
         lambda g: jax.lax.psum_scatter(g, axis_name, scatter_dimension=0,
                                        tiled=True)
-        if g.ndim > 0 and g.shape[0] % axis_size(axis_name) == 0
+        if g.ndim > 0 and g.shape[0] % jax.lax.axis_size(axis_name) == 0
         else jax.lax.psum(g, axis_name),
         grads)

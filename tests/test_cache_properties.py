@@ -173,6 +173,26 @@ def test_calibration_key_distinguishes_input_geometry():
     assert k4 == k1
 
 
+def test_cpu_timing_never_hydrates_a_tpu_plan(tmp_path, monkeypatch):
+    """Calibration is keyed by the device that timed it: a table measured
+    on the CPU is a miss, on both tiers, for a process on a TPU."""
+    import repro.core.session as session_mod
+
+    g = build_inception_like(n_blocks=1, width=2)
+    inputs = {0: jnp.ones((8, 64), jnp.float32)}
+    calib_dir = str(tmp_path / "calib")
+    table = Session(calib_dir=calib_dir).calibrate(g, inputs, repeats=1)
+    assert table.device == "cpu:cpu"
+    monkeypatch.setattr(session_mod, "measuring_device",
+                        lambda: "tpu:TPU v5 lite")
+    tpu = Session(calib_dir=calib_dir)
+    tpu.calibrate(g, inputs, repeats=1)
+    stats = tpu.cache_stats()
+    assert stats["calib_disk_hits"] == 0 and stats["calib_misses"] == 1
+    assert (calibration_key(g, inputs, "cpu:cpu")
+            != calibration_key(g, inputs, "tpu:TPU v5 lite"))
+
+
 def test_calibration_cache_evicts_lru():
     sess = Session(SessionConfig(cache_size=2))
     g = build_inception_like(n_blocks=1, width=2)

@@ -1,0 +1,99 @@
+"""Compile-only rehearsals of the main-path Pallas kernels for a TPU v5e.
+
+Each kernel is compiled, not run, for one chip of a described (not
+attached) v5e at qwen2-0.5b's widths (14 query heads over 2 KV heads, head
+dim 64, d_model 896, d_ff 4864), and must lower to a Mosaic
+``tpu_custom_call``.  This catches what interpret mode cannot: block
+shapes the TPU lowering refuses, and VMEM over-use.
+
+The topology is described only inside the module fixture (loading the TPU
+compiler while a module is imported would give pytest-xdist workers
+different test sets); keep every such test in this one file.
+"""
+import functools
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from repro.kernels.branch_gemm.kernel import branch_gemm_pallas
+from repro.kernels.branch_gemm.ops import select_tiles
+from repro.kernels.decode_attention.kernel import decode_attention_pallas
+from repro.kernels.flash_attention.kernel import flash_attention_pallas
+from repro.kernels.paged_decode.kernel import paged_decode_attention_pallas
+
+B, H, KVH, D, D_MODEL, D_FF = 4, 14, 2, 64, 896, 4864
+SEQ, CACHE, PAGE = 128, 256, 128
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import compilation_cache, topologies
+    from jax.sharding import SingleDeviceSharding
+
+    cache_on = jax.config.jax_enable_compilation_cache
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("TPU_LOG_DIR", "disabled")   # no compiler logs on disk
+        try:
+            topo = topologies.get_topology_desc(platform="tpu",
+                                                topology_name="v5e:2x2")
+        except Exception as e:
+            pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+        # a described chip's executable is written to the persistent cache
+        # but cannot be read back without one: keep these compiles out
+        jax.config.update("jax_enable_compilation_cache", False)
+        compilation_cache.compilation_cache.reset_cache()
+        try:
+            yield SingleDeviceSharding(topo.devices[0])
+        finally:
+            jax.config.update("jax_enable_compilation_cache", cache_on)
+
+
+def _branch_gemm():
+    # the captured gate||up wave: two [512, 896] @ [896, 4864] branches
+    m = B * SEQ
+    bm, bf, bk = select_tiles(m, D_MODEL, D_FF)
+    fn = functools.partial(branch_gemm_pallas, bm=bm, bf=bf, bk=bk,
+                           interpret=False)
+    return fn, [((2, m, D_MODEL), jnp.bfloat16),
+                ((2, D_MODEL, D_FF), jnp.bfloat16)]
+
+
+def _flash_attention():
+    fn = functools.partial(flash_attention_pallas, causal=True, window=0,
+                           bq=128, bk=128, interpret=False)
+    return fn, [((B, H, SEQ, D), jnp.bfloat16),
+                ((B, KVH, SEQ, D), jnp.bfloat16),
+                ((B, KVH, SEQ, D), jnp.bfloat16)]
+
+
+def _decode_attention():
+    fn = functools.partial(decode_attention_pallas, bk=CACHE, interpret=False)
+    return fn, [((B, H, D), jnp.bfloat16),
+                ((B, KVH, CACHE, D), jnp.bfloat16),
+                ((B, KVH, CACHE, D), jnp.bfloat16),
+                ((B, CACHE), jnp.int32)]
+
+
+def _paged_decode():
+    maxp = CACHE // PAGE
+    pages = 1 + B * maxp
+    fn = functools.partial(paged_decode_attention_pallas, scale=D ** -0.5,
+                           interpret=False)
+    return fn, [((B, H, D), jnp.bfloat16),
+                ((pages, KVH, PAGE, D), jnp.bfloat16),
+                ((pages, KVH, PAGE, D), jnp.bfloat16),
+                ((B * maxp,), jnp.int32),
+                ((B,), jnp.int32),
+                ((B,), jnp.int32)]
+
+
+@pytest.mark.parametrize("case", [_branch_gemm, _flash_attention,
+                                  _decode_attention, _paged_decode],
+                         ids=lambda c: c.__name__.lstrip("_"))
+def test_kernel_compiles_for_v5e(one_chip, case):
+    fn, args = case()
+    shapes = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+              for s, dt in args]
+    compiled = jax.jit(fn).lower(*shapes).compile()
+    assert "tpu_custom_call" in compiled.as_text()

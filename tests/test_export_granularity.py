@@ -152,3 +152,25 @@ def test_cost_only_exports_stream_ff_weights_payload_graphs_do_not():
     gp = build_lm_opgraph(cfg, 1, 4, params=params, n_layers=2)
     assert not any(n.name.endswith("_wstream") for n in gp)
     assert sum(1 for n in gp if n.fn is None) == 1
+
+
+@pytest.mark.parametrize("arch", ["qwen2-0.5b", "llama3.2-1b", "glm4-9b"])
+def test_payload_graph_matches_model_prefill(arch):
+    """A payload-backed dense export computes the model's forward pass
+    (rotary positions on q/k, fp32 logits): its last-position logits match
+    ``Model.prefill`` within bf16 rounding drift."""
+    import jax
+    import numpy as np
+
+    from repro.core.capture import run_sequential_uncompiled
+    from repro.models import Model
+
+    cfg = configs.get_config(arch, smoke=True)
+    model = Model(cfg)
+    params = model.init(jax.random.key(0))
+    tokens = jax.random.randint(jax.random.key(1), (2, 8), 0, cfg.vocab_size)
+    g = build_lm_opgraph(cfg, batch=2, seq=8, params=params)
+    out = np.asarray(run_sequential_uncompiled(g, {"tokens": tokens})[0])
+    ref = np.asarray(model.prefill(params, {"tokens": tokens})[0])
+    assert out.dtype == np.float32 and out.shape[:2] == (2, 8)
+    assert np.abs(out[:, -1] - ref).max() <= 2.5e-2 * np.abs(ref).max()

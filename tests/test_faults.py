@@ -243,7 +243,7 @@ def test_disk_read_fault_counts_and_falls_back_to_measure():
 
 def test_disk_tier_survives_concurrent_writers_and_corruption(tmp_path):
     d = str(tmp_path / "calib-conc")
-    tables = {i: ProfileTable(hw_name="v5e",
+    tables = {i: ProfileTable(device="tpu:TPU v5 lite",
                               measured_us=((0, 1.0 + i), (1, 2.0 * i + 1.0)))
               for i in range(8)}
     corrupting = FaultPlan.single("calib_disk_write", mode="corrupt", times=1)

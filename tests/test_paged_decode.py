@@ -12,7 +12,7 @@ from repro.kernels.paged_decode.ops import (paged_decode_attention,
 from repro.kernels.paged_decode.ref import paged_decode_attention_ref
 from repro.runtime.guard import kernel_log
 
-# on-lattice interpret-mode geometry: grid = b*h*maxp = 2*4*2 = 16 <= limit
+# on-lattice interpret-mode geometry: grid = b*kvh*maxp = 2*2*2 = 8 <= limit
 B, H, KVH, DK, DV, PS, NPAGES, MAXP = 2, 4, 2, 8, 8, 128, 6, 2
 
 
@@ -162,7 +162,7 @@ def test_interpret_grid_guard_routes_ref_silently():
     degradation event (a route decision, not a failure)."""
     from repro.kernels import INTERPRET_GRID_LIMIT
 
-    maxp = INTERPRET_GRID_LIMIT // (B * H) + 1
+    maxp = INTERPRET_GRID_LIMIT // (B * KVH) + 1
     npages = maxp + 1
     ks = jax.random.split(jax.random.PRNGKey(13), 3)
     q = jax.random.normal(ks[0], (B, H, DK), jnp.float32)

@@ -318,7 +318,7 @@ def test_calibration_disk_corruption_falls_back(tmp_path):
     inputs = {0: jnp.ones((8, 64), jnp.float32)}
     sess = Session(calib_dir=calib_dir)
     sess.calibrate(g, inputs, repeats=1)
-    path = _calib_path(calibration_key(g, inputs, V5E), calib_dir)
+    path = _calib_path(calibration_key(g, inputs), calib_dir)
     with open(path, "w") as f:
         f.write("{not json")
     sess.clear_caches()

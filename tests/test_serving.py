@@ -370,3 +370,17 @@ def test_paged_unsupported_family_degrades_to_dense():
     engine.submit(Request(rid=0, prompt=[1, 2, 3], max_tokens=3))
     done = engine.run(100)
     assert done[0].state is RequestState.DONE
+
+
+def test_serve_paged_returns_fallback_ledger():
+    """``serve(paged_kv=True)`` runs the paged engine and returns what a
+    caller needs to tell a clean run from a degraded one: the engine's
+    fault counters and the recorded fallbacks."""
+    from repro.launch.serve import serve
+
+    res = serve("qwen2-0.5b", n_requests=3, max_tokens=4, slots=2,
+                paged_kv=True)
+    assert res["completed"] == 3 and res["total_tokens"] == 12
+    assert res["fault_stats"]["paged_decode_fallbacks"] == 0
+    assert res["fault_stats"]["failed_requests"] == 0
+    assert res["degradations"] == []
