@@ -1,0 +1,8 @@
+"""device_idle.graph: 1 - (union of device-op intervals) / traced window
+(device trace, %)."""
+
+
+def read(run):
+    if run.trace is None or run.kind != "graph":
+        return None
+    return 100.0 * (1.0 - run.trace.busy_s / run.trace.window_s)
