@@ -49,6 +49,7 @@ import jax
 import jax.numpy as jnp
 
 from ..kernels import INTERPRET_GRID_LIMIT as _INTERPRET_GRID_LIMIT
+from ..runtime import tracing
 from ..runtime.faults import FaultPlan, get_active as _active_faults
 from ..runtime.guard import DegradationLog
 from .fusion import WaveSchedule
@@ -110,22 +111,27 @@ class CapturedGraph:
                 self.graph.nodes[i].name for i in self.input_ids)
 
     def __call__(self, inputs: Mapping[str, Any]) -> list[Any]:
-        args = self._bind(inputs)
-        try:
-            return self.jitted(*args)
-        except Exception as exc:
-            # bottom rung of the ladder: the compiled program failed to
-            # trace/launch — replay per-op in topo order (the differential
-            # harness's own ground truth).  If that fails too, the original
-            # error was real: surface it, not the fallback's.
+        with tracing.span("capture.call"):
+            with tracing.span("capture.bind"):
+                args = self._bind(inputs)
             try:
-                outs = run_sequential_uncompiled(self.graph, inputs,
-                                                 self.output_ids)
-            except Exception:
-                raise exc
-            self.degradations.note("execute", "jitted->sequential",
-                                   repr(exc), warn=True)
-            return outs
+                # until the program is enqueued; the caller blocks on it
+                with tracing.span("capture.dispatch"):
+                    return self.jitted(*args)
+            except Exception as exc:
+                # bottom rung of the ladder: the compiled program failed to
+                # trace/launch — replay per-op in topo order (the
+                # differential harness's own ground truth).  If that fails
+                # too, the original error was real: surface it, not the
+                # fallback's.
+                try:
+                    outs = run_sequential_uncompiled(self.graph, inputs,
+                                                     self.output_ids)
+                except Exception:
+                    raise exc
+                self.degradations.note("execute", "jitted->sequential",
+                                       repr(exc), warn=True)
+                return outs
 
     def call_uncompiled(self, inputs: Mapping[str, Any]) -> list[Any]:
         args = self._bind(inputs)
@@ -592,19 +598,21 @@ def capture(
         for s, a in zip(input_slots, args):
             env[s] = a
         for step, cs in zip(steps, consts):
-            if step.route == _CALL:
-                out = step.fn(*[env[s] for s in step.arg_slots], *cs)
-                env[step.out_slots[0]] = out
-            elif step.route == _GROUPED_GEMM:
-                outs = step.fn([env[s] for s in step.arg_slots[0]], *cs)
-                for k, slot in enumerate(step.out_slots):
-                    env[slot] = outs[k]
-            else:
-                stacked = [jnp.stack([env[s] for s in slots])
-                           for slots in step.arg_slots]
-                outs = step.fn(*stacked, *cs)
-                for k, slot in enumerate(step.out_slots):
-                    env[slot] = tree_map(lambda x: x[k], outs)
+            # each step's operations carry its route in their metadata
+            with jax.named_scope(step.route):
+                if step.route == _CALL:
+                    out = step.fn(*[env[s] for s in step.arg_slots], *cs)
+                    env[step.out_slots[0]] = out
+                elif step.route == _GROUPED_GEMM:
+                    outs = step.fn([env[s] for s in step.arg_slots[0]], *cs)
+                    for k, slot in enumerate(step.out_slots):
+                        env[slot] = outs[k]
+                else:
+                    stacked = [jnp.stack([env[s] for s in slots])
+                               for slots in step.arg_slots]
+                    outs = step.fn(*stacked, *cs)
+                    for k, slot in enumerate(step.out_slots):
+                        env[slot] = tree_map(lambda x: x[k], outs)
             for s in step.free_slots:
                 env[s] = None
         return [env[s] for s in output_slots]

@@ -52,6 +52,7 @@ from typing import Any, Mapping
 
 import numpy as np
 
+from ..runtime import tracing
 from ..runtime.faults import FaultPlan, get_active as _active_faults
 from ..runtime.guard import DegradationLog, retry_with_backoff
 from .capture import CapturedGraph
@@ -702,22 +703,22 @@ class Session:
         whether this build hit or missed.
         """
         cfg = self.config
-        t_total0 = time.perf_counter()
         mark = len(self.guard_log)        # events from THIS build start here
-        timings = {"calibrate": 0.0, "plan": 0.0, "compile": 0.0}
         provenance = {"calibration": "off"}
-        if inputs is not None:
-            t0 = time.perf_counter()
-            _, provenance["calibration"] = self._calibrate(graph, inputs, cfg)
-            timings["calibrate"] = (time.perf_counter() - t0) * 1e3
-        t0 = time.perf_counter()
-        p, provenance["plan"] = self._plan(graph, cfg)
-        timings["plan"] = (time.perf_counter() - t0) * 1e3
-        t0 = time.perf_counter()
-        exe, provenance["executable"] = self._capture(graph, cfg, p,
-                                                      output_ids=output_ids)
-        timings["compile"] = (time.perf_counter() - t0) * 1e3
-        timings["total"] = (time.perf_counter() - t_total0) * 1e3
+        # timed spans: the stage times hold with the tracer off as well
+        cal = tracing.timed("session.calibrate")   # stays 0 ms if not run
+        with tracing.timed("session.compile") as total:
+            if inputs is not None:
+                with cal:
+                    _, provenance["calibration"] = self._calibrate(
+                        graph, inputs, cfg)
+            with tracing.timed("session.plan") as plan:
+                p, provenance["plan"] = self._plan(graph, cfg)
+            with tracing.timed("session.capture") as cap:
+                exe, provenance["executable"] = self._capture(
+                    graph, cfg, p, output_ids=output_ids)
+        timings = {"calibrate": cal.ms, "plan": plan.ms, "compile": cap.ms,
+                   "total": total.ms}
         return CompiledModel(config=cfg, graph=graph, plan=p,
                              executable=exe, provenance=provenance,
                              timings_ms=timings,

@@ -7,6 +7,7 @@ rungs compute the identical function (tests assert allclose).
 """
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 from .. import INTERPRET_GRID_LIMIT, interpret_mode
@@ -44,8 +45,9 @@ def paged_decode_attention(q, k_pages, v_pages, block_tables, lengths,
         return paged_decode_attention_ref(q, k_pages, v_pages, block_tables,
                                           lengths, starts, scale)
     try:
-        kt = jnp.swapaxes(k_pages, 1, 2)               # [P, KVH, ps, Dk]
-        vt = jnp.swapaxes(v_pages, 1, 2)
+        with jax.named_scope("kv_layout"):
+            kt = jnp.swapaxes(k_pages, 1, 2)           # [P, KVH, ps, Dk]
+            vt = jnp.swapaxes(v_pages, 1, 2)
         return paged_decode_attention_pallas(
             q, kt, vt, block_tables.reshape(-1), starts, lengths,
             scale=scale, interpret=interpret_mode())

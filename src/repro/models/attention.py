@@ -539,20 +539,22 @@ def gqa_paged_decode(p, x, pages, block_tables, pos, cfg: ModelConfig,
     rows = jnp.arange(b)
     page = block_tables[rows, pos // ps]                # [B] physical pages
     off = pos % ps
-    k_pages = k_pages.at[page, off].set(k[:, 0].astype(k_pages.dtype))
-    v_pages = v_pages.at[page, off].set(v[:, 0].astype(v_pages.dtype))
+    with jax.named_scope("pool_write"):
+        k_pages = k_pages.at[page, off].set(k[:, 0].astype(k_pages.dtype))
+        v_pages = v_pages.at[page, off].set(v[:, 0].astype(v_pages.dtype))
     lengths = (pos + 1).astype(jnp.int32)
     starts = None
     if window is not None:
         starts = jnp.clip(pos - window + 1, 0).astype(jnp.int32)
-    if use_kernels:
-        from ..kernels.paged_decode.ops import paged_decode_attention
-        out = paged_decode_attention(q[:, 0], k_pages, v_pages, block_tables,
-                                     lengths, starts)
-    else:
-        from ..kernels.paged_decode.ref import paged_decode_attention_ref
-        out = paged_decode_attention_ref(q[:, 0], k_pages, v_pages,
+    with jax.named_scope("attention"):
+        if use_kernels:
+            from ..kernels.paged_decode.ops import paged_decode_attention
+            out = paged_decode_attention(q[:, 0], k_pages, v_pages,
                                          block_tables, lengths, starts)
+        else:
+            from ..kernels.paged_decode.ref import paged_decode_attention_ref
+            out = paged_decode_attention_ref(q[:, 0], k_pages, v_pages,
+                                             block_tables, lengths, starts)
     y = linear(p["wo"], out.reshape(b, 1, h * hd))
     return y, (k_pages, v_pages)
 
@@ -570,8 +572,11 @@ def mla_paged_decode(p, x, pages, block_tables, pos, cfg: ModelConfig,
     rows = jnp.arange(b)
     page = block_tables[rows, pos // ps]
     off = pos % ps
-    ckv_pages = ckv_pages.at[page, off].set(c_new[:, 0].astype(ckv_pages.dtype))
-    kpe_pages = kpe_pages.at[page, off].set(r_new[:, 0].astype(kpe_pages.dtype))
+    with jax.named_scope("pool_write"):
+        ckv_pages = ckv_pages.at[page, off].set(
+            c_new[:, 0].astype(ckv_pages.dtype))
+        kpe_pages = kpe_pages.at[page, off].set(
+            r_new[:, 0].astype(kpe_pages.dtype))
     lengths = (pos + 1).astype(jnp.int32)
     scale = (m.qk_nope_head_dim + m.qk_rope_head_dim) ** -0.5
     wk_b = p["wk_b"]["w"].reshape(rank, h, m.qk_nope_head_dim)

@@ -420,11 +420,12 @@ def block_step_paged(p, x, pages, block_tables, pos, cfg: ModelConfig, window,
                                             pos, cfg, window, use_kernels)
     x = x + attn_out * cfg.residual_scale
     h2 = apply_norm(p["norm2"], x, cfg.norm)
-    if layer_kind == "dense_prefix":
-        from .ffn import mlp
-        f_out = mlp(p["ffn"], h2, cfg.act)
-    else:
-        f_out, _ = ffn(p["ffn"], h2, cfg, None, use_kernels)
+    with jax.named_scope("mlp"):
+        if layer_kind == "dense_prefix":
+            from .ffn import mlp
+            f_out = mlp(p["ffn"], h2, cfg.act)
+        else:
+            f_out, _ = ffn(p["ffn"], h2, cfg, None, use_kernels)
     x = x + f_out * cfg.residual_scale
     return x, new_pages
 

@@ -77,6 +77,10 @@ class Request:
     submit_tick: int = -1             # set by ``submit()``
     finish_tick: int = -1             # tick the request went terminal
     preemptions: int = 0              # times evicted for a more urgent one
+    # perf_counter_ns at which the request last entered the queue, for its
+    # ``engine.queued`` span; None while the tracer is off
+    queued_ns: int | None = dataclasses.field(default=None, repr=False,
+                                              compare=False)
 
     def ticks_needed(self) -> int:
         """Engine ticks to finish from a cold start: one prefill tick

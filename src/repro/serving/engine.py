@@ -40,6 +40,7 @@ in its own ``guard_log``.
 from __future__ import annotations
 
 import copy
+import time
 import warnings
 import weakref
 from typing import Any, Mapping
@@ -50,6 +51,7 @@ import numpy as np
 
 from ..configs.base import ModelConfig
 from ..models import Model
+from ..runtime import tracing
 from ..runtime.faults import FaultInjected, FaultPlan
 from ..runtime.faults import get_active as _active_faults
 from ..runtime.guard import DegradationWarning
@@ -414,10 +416,16 @@ class InferenceEngine:
                 f"(max_len={self.max_len} incl. at least one decode "
                 "position); rejected at admission")))
             return req
-        admitted, shed, reason = self.admission.offer(req, self.tick)
+        admitted, shed, reason = self._offer(req)
         for victim in shed:
             self._terminal.append(self._shed(victim, reason))
         return req
+
+    def _offer(self, req: Request):
+        """``admission.offer`` that stamps the time ``req`` entered the
+        queue."""
+        req.queued_ns = time.perf_counter_ns() if tracing.enabled() else None
+        return self.admission.offer(req, self.tick)
 
     def run(self, max_ticks: int = 1000) -> list[Request]:
         """Tick until all work is terminal or ``max_ticks`` is exhausted.
@@ -471,18 +479,30 @@ class InferenceEngine:
 
     # -- one tick -----------------------------------------------------------------
     def step(self) -> list[Request]:
-        self.tick += 1
-        out = self._drain_terminal()
-        out.extend(self._deadline_sweep())
-        free = [i for i, s in enumerate(self.slots) if s is None]
-        if free and len(self.admission):
-            req = self.admission.pop_next()
-            out.extend(self._admit(free[0], req))
-            return out
-        if not free and len(self.admission) and self.admission_cfg.preemption:
-            out.extend(self._maybe_preempt())
-        out.extend(self._paged_decode_tick() if self.paged
-                   else self._decode_tick())
+        with tracing.span("engine.step") as sp:
+            self.tick += 1
+            out = self._drain_terminal()
+            out.extend(self._deadline_sweep())
+            free = [i for i, s in enumerate(self.slots) if s is None]
+            if tracing.enabled():
+                sp.set(active=self.max_slots - len(free))
+                if self.paged:
+                    sp.set(used_pages=self.pool.used_pages,
+                           free_pages=self.pool.free_pages)
+            if free and len(self.admission):
+                req = self.admission.pop_next()
+                if req.queued_ns is not None:
+                    tracing.record("engine.queued", req.queued_ns,
+                                   time.perf_counter_ns(), rid=req.rid)
+                sp.set(kind="admit")
+                out.extend(self._admit(free[0], req))
+                return out
+            sp.set(kind="decode" if len(free) < self.max_slots else "idle")
+            if not free and len(self.admission) \
+                    and self.admission_cfg.preemption:
+                out.extend(self._maybe_preempt())
+            out.extend(self._paged_decode_tick() if self.paged
+                       else self._decode_tick())
         return out
 
     def _work_pending(self) -> bool:
@@ -560,7 +580,7 @@ class InferenceEngine:
                   f"rid={cand.rid} (priority {cand.priority} > "
                   f"{victim.priority}, deadline {cand.deadline})")
         self._tenant_note(victim, "slot_preempt", "running->requeued", reason)
-        admitted, shed, shed_reason = self.admission.offer(victim, self.tick)
+        admitted, shed, shed_reason = self._offer(victim)
         for req in shed:
             self._terminal.append(
                 self._shed(req, f"preempted then {shed_reason}"))
@@ -588,30 +608,49 @@ class InferenceEngine:
             # count the re-prefilled tokens so the paged path's zero here
             # is a measurable win, not an assertion
             self.fault_stats["reprefilled_tokens"] += len(tokens_list)
-        tokens = jnp.asarray([tokens_list], jnp.int32)
-        try:
-            logits, cache = self.model.prefill(
-                self.params, {"tokens": tokens},
-                cache_len=self.max_len + self.cfg.meta_tokens)
-        except Exception as exc:
-            # a poisoned prompt must not take the engine down — the queue
-            # keeps draining and the decode batch never saw this request
-            return [self._fail(req, f"prefill failed: {exc!r}")]
-        if not bool(np.isfinite(np.asarray(logits)).all()):
-            return [self._fail(req, "prefill produced non-finite logits")]
-        self.rng, sub = jax.random.split(self.rng)
-        first = int(sample_token(logits, sub, req.temperature)[0])
+        first, cache = self._prefill_first(
+            req, tokens_list, self.max_len + self.cfg.meta_tokens)
+        if first is None:
+            return [self._fail(req, cache)]
         req.output.append(first)
         if (req.eos_id is not None and first == req.eos_id) \
                 or len(req.output) >= req.max_tokens:
             return [self._complete(req)]
         # splice the single-request cache into the shared slot cache
-        self.caches = jax.tree_util.tree_map(
-            lambda big, small: _splice(big, small, slot), self.caches, cache)
+        with tracing.span("engine.admit.scatter"):
+            self.caches = jax.tree_util.tree_map(
+                lambda big, small: _splice(big, small, slot), self.caches,
+                cache)
         self.slots[slot] = req
         self.pos[slot] = len(tokens_list)
         self.last_token[slot] = first
         return []
+
+    def _prefill_first(self, req: Request, tokens_list: list[int],
+                       cache_len: int):
+        """Prefill ``tokens_list`` and sample the request's next token.
+        Returns (token, batch-1 cache), or (None, why it failed)."""
+        tokens = jnp.asarray([tokens_list], jnp.int32)
+        with tracing.span("engine.admit.prefill") as sp:
+            before = tracing.compiles() if tracing.enabled() else None
+            try:
+                logits, cache = self.model.prefill(
+                    self.params, {"tokens": tokens}, cache_len=cache_len)
+            except Exception as exc:
+                # a poisoned prompt must not take the engine down — the
+                # queue keeps draining and the decode batch never saw it
+                return None, f"prefill failed: {exc!r}"
+            finite = bool(np.isfinite(np.asarray(logits)).all())
+            if before is not None:
+                # the eager prefill re-traces its scan on every admission
+                sp.set(tokens=len(tokens_list),
+                       **tracing.compiles_since(before))
+        if not finite:
+            return None, "prefill produced non-finite logits"
+        with tracing.span("engine.admit.sample"):
+            self.rng, sub = jax.random.split(self.rng)
+            first = int(sample_token(logits, sub, req.temperature)[0])
+        return first, cache
 
     # -- paged KV path ------------------------------------------------------------
     def _admit_paged(self, slot: int, req: Request,
@@ -642,26 +681,20 @@ class InferenceEngine:
         except PageExhausted as exc:
             self.fault_stats["page_exhaustions"] += 1
             return self._page_pressure(req, str(exc))
-        tokens = jnp.asarray([tokens_list], jnp.int32)
-        try:
-            # page-aligned dense intermediate so the scatter below covers
-            # every written position without bounds logic
-            logits, cache = self.model.prefill(
-                self.params, {"tokens": tokens},
-                cache_len=self._pages_per_req * ps)
-        except Exception as exc:
-            return [self._fail(req, f"prefill failed: {exc!r}")]
-        if not bool(np.isfinite(np.asarray(logits)).all()):
-            return [self._fail(req, "prefill produced non-finite logits")]
-        self.rng, sub = jax.random.split(self.rng)
-        first = int(sample_token(logits, sub, req.temperature)[0])
+        # page-aligned dense intermediate so the scatter below covers every
+        # written position without bounds logic
+        first, cache = self._prefill_first(req, tokens_list,
+                                           self._pages_per_req * ps)
+        if first is None:
+            return [self._fail(req, cache)]
         req.output.append(first)
         if had_output:
             self.fault_stats["reprefilled_tokens"] += len(tokens_list)
         if (req.eos_id is not None and first == req.eos_id) \
                 or len(req.output) >= req.max_tokens:
             return [self._complete(req)]
-        self._scatter_pages(req, cache, n_pos, skip_pages=shared)
+        with tracing.span("engine.admit.scatter"):
+            self._scatter_pages(req, cache, n_pos, skip_pages=shared)
         if keys is not None:
             self.pool.publish_keys(req.rid, keys)
         self.slots[slot] = req
@@ -744,7 +777,7 @@ class InferenceEngine:
                 f"(bounced {bounces}x, limit {self.page_bounce_limit})"))]
         req.state = RequestState.PENDING
         self._tenant_note(req, "page_alloc", "running->requeued", reason)
-        admitted, shed, shed_reason = self.admission.offer(req, self.tick)
+        admitted, shed, shed_reason = self._offer(req)
         return [self._shed(victim, f"page pressure requeue: {shed_reason}")
                 for victim in shed]
 
@@ -790,49 +823,58 @@ class InferenceEngine:
         out: list[Request] = []
         faults = self._faults()
         still = []
-        for i in active:
-            req = self.slots[i]
-            wp = int(self.pos[i]) + self.cfg.meta_tokens
-            try:
-                if faults is not None:
-                    faults.fire("page_alloc")
-                self.pool.ensure(req.rid, wp + 1, req.tenant)
-                page, copy_src = self.pool.writable_page(req.rid, wp)
-            except FaultInjected as exc:
-                self.fault_stats["page_alloc_faults"] += 1
-                self._clear_slot(i)
-                out.extend(self._page_pressure(req, f"{exc}"))
-                continue
-            except PageExhausted as exc:
-                self.fault_stats["page_exhaustions"] += 1
-                self._clear_slot(i)
-                out.extend(self._page_pressure(req, str(exc)))
-                continue
-            if copy_src is not None:
-                self._copy_page(page, copy_src)
-            still.append(i)
+        err = None
+        with tracing.span("engine.decode.prepare"):
+            for i in active:
+                req = self.slots[i]
+                wp = int(self.pos[i]) + self.cfg.meta_tokens
+                try:
+                    if faults is not None:
+                        faults.fire("page_alloc")
+                    self.pool.ensure(req.rid, wp + 1, req.tenant)
+                    page, copy_src = self.pool.writable_page(req.rid, wp)
+                except FaultInjected as exc:
+                    self.fault_stats["page_alloc_faults"] += 1
+                    self._clear_slot(i)
+                    out.extend(self._page_pressure(req, f"{exc}"))
+                    continue
+                except PageExhausted as exc:
+                    self.fault_stats["page_exhaustions"] += 1
+                    self._clear_slot(i)
+                    out.extend(self._page_pressure(req, str(exc)))
+                    continue
+                if copy_src is not None:
+                    self._copy_page(page, copy_src)
+                still.append(i)
+            if still:
+                token = jnp.asarray(self.last_token)
+                pos = jnp.asarray(self.pos)
+                try:
+                    if faults is not None:
+                        faults.fire("block_table_build")
+                    bt = jnp.asarray(self._block_table_array())
+                except Exception as exc:
+                    err = exc
         if not still:
             return out
-        token = jnp.asarray(self.last_token)
-        pos = jnp.asarray(self.pos)
-        logits = None
-        try:
-            if faults is not None:
-                faults.fire("block_table_build")
-            bt = jnp.asarray(self._block_table_array())
-            logits, caches = self._paged_decode(self.params, self.caches,
-                                                token, bt, pos)
-            if faults is not None:
-                logits = faults.fire("decode_step", payload=logits)
-            self.caches = caches
-        except Exception as exc:
-            logits = self._paged_fallback(exc)
+        if err is None:
+            try:
+                with tracing.span("engine.decode.dispatch"):
+                    logits, caches = self._paged_decode(
+                        self.params, self.caches, token, bt, pos)
+                    if faults is not None:
+                        logits = faults.fire("decode_step", payload=logits)
+                self.caches = caches
+            except Exception as exc:
+                err = exc
+        if err is not None:
+            logits = self._paged_fallback(err)
             if logits is None:
                 for i in still:
                     req = self.slots[i]
                     self._clear_slot(i)
                     out.append(self._fail(
-                        req, f"paged decode failed on both rungs: {exc!r}"))
+                        req, f"paged decode failed on both rungs: {err!r}"))
                 return out
         out.extend(self._advance_slots(still, logits))
         return out
@@ -891,19 +933,22 @@ class InferenceEngine:
         active = [i for i, s in enumerate(self.slots) if s is not None]
         if not active:
             return []
-        token = jnp.asarray(self.last_token)
-        pos = jnp.asarray(self.pos)
+        with tracing.span("engine.decode.prepare"):
+            token = jnp.asarray(self.last_token)
+            pos = jnp.asarray(self.pos)
         logits = None
         faults = self._faults()
         if self._use_compiled:
             try:
-                logits, caches = self._decode(self.params, self.caches,
-                                              token, pos)
-                if faults is not None:
-                    # raise mode → watchdog; corrupt mode → one poisoned
-                    # slot (NaN row), caught per-slot below.  Fired only on
-                    # the compiled path so the eager rescue never re-injects.
-                    logits = faults.fire("decode_step", payload=logits)
+                with tracing.span("engine.decode.dispatch"):
+                    logits, caches = self._decode(self.params, self.caches,
+                                                  token, pos)
+                    if faults is not None:
+                        # raise mode → watchdog; corrupt mode → one poisoned
+                        # slot (NaN row), caught per-slot below.  Fired only
+                        # on the compiled path so the eager rescue never
+                        # re-injects.
+                        logits = faults.fire("decode_step", payload=logits)
                 self.caches = caches
             except Exception as exc:
                 # step watchdog: latch onto the eager (uncompiled) step —
@@ -923,8 +968,9 @@ class InferenceEngine:
                 logits = None
         if logits is None:
             try:
-                logits, self.caches = self.model.decode(
-                    self.params, token, self.caches, pos)
+                with tracing.span("engine.decode.dispatch", eager=True):
+                    logits, self.caches = self.model.decode(
+                        self.params, token, self.caches, pos)
             except Exception as exc:
                 # both rungs failed: fail the co-batch explicitly rather
                 # than crash mid-tick with slots in limbo
@@ -955,29 +1001,34 @@ class InferenceEngine:
     def _advance_slots(self, active: list[int], logits) -> list[Request]:
         """Per-slot sampling/completion tail shared by the dense and paged
         decode ticks (identical rng discipline → identical token streams)."""
-        finite_rows = np.isfinite(np.asarray(logits)).all(axis=-1)
-        self.rng, sub = jax.random.split(self.rng)
-        finished: list[Request] = []
-        for i in active:
-            req = self.slots[i]
-            if not bool(finite_rows[i]):
-                # poisoned request: evict THIS slot only; the other slots'
-                # logits and cache rows are intact and keep decoding
-                self.fault_stats["decode_faults"] += 1
-                finished.append(self._fail(
-                    req, "decode produced non-finite logits"))
-                self._clear_slot(i)
-                continue
-            t = int(sample_token(logits[i:i + 1], jax.random.fold_in(sub, i),
-                                 req.temperature)[0])
-            req.output.append(t)
-            self.pos[i] += 1
-            self.last_token[i] = t
-            hit_eos = req.eos_id is not None and t == req.eos_id
-            if hit_eos or len(req.output) >= req.max_tokens \
-                    or self.pos[i] >= self.max_len - 1:
-                finished.append(self._complete(req))
-                self._clear_slot(i)
+        # the one place a decode tick waits for the device
+        with tracing.span("engine.decode.wait"):
+            finite_rows = np.isfinite(np.asarray(logits)).all(axis=-1)
+        with tracing.span("engine.decode.sample"):
+            self.rng, sub = jax.random.split(self.rng)
+            finished: list[Request] = []
+            for i in active:
+                req = self.slots[i]
+                if not bool(finite_rows[i]):
+                    # poisoned request: evict THIS slot only; the other
+                    # slots' logits and cache rows are intact and keep
+                    # decoding
+                    self.fault_stats["decode_faults"] += 1
+                    finished.append(self._fail(
+                        req, "decode produced non-finite logits"))
+                    self._clear_slot(i)
+                    continue
+                t = int(sample_token(logits[i:i + 1],
+                                     jax.random.fold_in(sub, i),
+                                     req.temperature)[0])
+                req.output.append(t)
+                self.pos[i] += 1
+                self.last_token[i] = t
+                hit_eos = req.eos_id is not None and t == req.eos_id
+                if hit_eos or len(req.output) >= req.max_tokens \
+                        or self.pos[i] >= self.max_len - 1:
+                    finished.append(self._complete(req))
+                    self._clear_slot(i)
         return finished
 
 
