@@ -49,6 +49,7 @@ import jax
 import jax.numpy as jnp
 
 from ..kernels import INTERPRET_GRID_LIMIT as _INTERPRET_GRID_LIMIT
+from ..kernels.branch_gemm.ops import grid_steps, launch_grid
 from ..runtime import tracing
 from ..runtime.faults import FaultPlan, get_active as _active_faults
 from ..runtime.guard import DegradationLog
@@ -83,6 +84,8 @@ class Step:
     op_ids: tuple[int, ...]             # provenance (tests / debugging)
     group_sizes: tuple[int, ...] = ()   # _GROUPED_GEMM: per-branch row counts
                                         # (the capture-time offset table)
+    grid: int | None = None             # _BRANCH_GEMM: kernel grid steps a
+                                        # call, set when the program traces
 
 
 @dataclasses.dataclass
@@ -154,14 +157,22 @@ class CapturedGraph:
         return args
 
     def program_stats(self) -> dict[str, float]:
+        """Route counts; once the program has been traced, also
+        ``branch_gemm_grid``: the grid steps a call of the traced shapes
+        launches across its ``branch_gemm`` steps (the kernel's own tile
+        rule, :func:`repro.kernels.branch_gemm.ops.launch_grid`)."""
         routes = [s.route for s in self.steps]
-        return {
+        stats = {
             "n_steps": float(len(self.steps)),
             "n_single": float(routes.count(_CALL)),
             "n_vmap": float(routes.count(_VMAP)),
             "n_branch_gemm": float(routes.count(_BRANCH_GEMM)),
             "n_grouped_gemm": float(routes.count(_GROUPED_GEMM)),
         }
+        grids = [s.grid for s in self.steps if s.route == _BRANCH_GEMM]
+        if None not in grids:
+            stats["branch_gemm_grid"] = float(sum(grids))
+        return stats
 
 
 def _branch_input_shapes(
@@ -290,15 +301,15 @@ def _pick_gemm_route(w: jax.Array, n_branches: int, gemm_kernel: str,
                      m: int | None = None) -> str:
     """Decide Pallas vs vmap for an eligible GEMM group (capture time).
 
-    The interpret-mode grid estimate runs the SAME tile selection as the
-    ``branch_gemm`` wrapper (``select_tiles``), so the decision counts the
-    grid the kernel would actually launch — including the M dimension when
-    the branch input shape is declared.  ``m=None`` (undeclared shape)
-    counts a single row tile, matching the legacy M-blind estimate — an
-    optimistic floor, so builders that want the exact decision should
-    declare ``out_shape`` on branch inputs.  Non-tileable shapes go to the
-    kernel wrapper's einsum-ref fallback, which is one fused op with no
-    unrolled grid.
+    The interpret-mode grid estimate runs the SAME tile rule as the
+    ``branch_gemm`` wrapper (``grid_steps`` over ``select_tiles``, at the
+    weights' dtype), so the decision counts the grid the kernel would
+    actually launch — including the M dimension when the branch input
+    shape is declared.  ``m=None`` (undeclared shape) counts the grid of
+    an 8-row input, a single row tile — an optimistic floor, so a graph
+    that wants the exact decision should declare ``out_shape`` on branch
+    inputs.  Non-tileable shapes go to the kernel wrapper's einsum-ref
+    fallback, which is one fused op with no unrolled grid.
     """
     if gemm_kernel == "vmap":
         return _VMAP
@@ -307,17 +318,13 @@ def _pick_gemm_route(w: jax.Array, n_branches: int, gemm_kernel: str,
     # "auto": on TPU always take the fused kernel; on CPU (interpret mode)
     # only when the unrolled grid stays small.
     from ..kernels import interpret_mode
-    from ..kernels.branch_gemm.ops import select_tiles
 
     if not interpret_mode():
         return _BRANCH_GEMM
     k, f = w.shape
-    tiles = select_tiles(m if m is not None else 8, k, f)
-    if tiles is None:
-        return _BRANCH_GEMM   # einsum-ref fallback: fused, no grid
-    bm, bf, bk = tiles
-    m_tiles = (m // bm) if m is not None else 1
-    grid_points = n_branches * m_tiles * (f // bf) * (k // bk)
+    # 0 steps where not tileable: the einsum-ref fallback, fused, no grid
+    grid_points = grid_steps(n_branches, m if m is not None else 8, k, f,
+                             w.dtype.itemsize)
     return _BRANCH_GEMM if grid_points <= _INTERPRET_GRID_LIMIT else _VMAP
 
 
@@ -611,6 +618,8 @@ def capture(
                     stacked = [jnp.stack([env[s] for s in slots])
                                for slots in step.arg_slots]
                     outs = step.fn(*stacked, *cs)
+                    if step.route == _BRANCH_GEMM:
+                        step.grid = launch_grid(stacked[0], cs[0])
                     for k, slot in enumerate(step.out_slots):
                         env[slot] = tree_map(lambda x: x[k], outs)
             for s in step.free_slots:

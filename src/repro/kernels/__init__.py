@@ -8,7 +8,9 @@ Each kernel lives in its own subpackage with the mandated layout:
 
 Kernels:
     branch_gemm       horizontally-fused multi-branch GEMM — the Opara wave
-                      (N independent small GEMMs → one MXU-saturating kernel)
+                      (N independent small GEMMs → one MXU-saturating kernel);
+                      shape-aware tiles: the whole of K, and row and column
+                      tiles as large as the v5e's scoped VMEM holds
     grouped_gemm      ragged-M grouped GEMM (unequal branch row counts, MoE
                       expert fan-out) — scalar-prefetched tile→group table
     flash_attention   causal/windowed GQA flash attention (prefill/train)

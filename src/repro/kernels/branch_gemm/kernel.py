@@ -11,8 +11,12 @@ realized by Pallas' automatic pipelining across sequential grid steps).
 
     x: [N, M, K]   w: [N, K, F]   out: [N, M, F]
 
-Grid: (N, M/bm, F/bf, K/bk) — K innermost so the fp32 VMEM accumulator
-carries across K tiles of one (branch, m, f) block.
+Tiles come from ``ops.select_tiles``: the whole of K in one block, with
+row and column tiles as large as VMEM holds.  Grid: (N, M/bm, F/bf) — with
+one K block the x block's index does not change across the F tiles of a
+row tile, so the pipeline fetches each row tile of x once, and each output
+block is one MXU pass with fp32 accumulation, written straight out in the
+output dtype.
 """
 from __future__ import annotations
 
@@ -21,52 +25,37 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
 
-def _kernel(x_ref, w_ref, o_ref, acc_ref):
-    k = pl.program_id(3)
-
-    @pl.when(k == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    acc_ref[...] += jax.lax.dot_general(
+def _kernel(x_ref, w_ref, o_ref):
+    o_ref[0] = jax.lax.dot_general(
         x_ref[0], w_ref[0],
         dimension_numbers=(((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32,
-    )
-
-    @pl.when(k == pl.num_programs(3) - 1)
-    def _store():
-        o_ref[0] = acc_ref[...].astype(o_ref.dtype)
+    ).astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("bm", "bf", "bk", "interpret"))
+@functools.partial(jax.jit, static_argnames=("bm", "bf", "interpret"))
 def branch_gemm_pallas(
     x: jax.Array,
     w: jax.Array,
-    bm: int = 128,
-    bf: int = 128,
-    bk: int = 512,
+    bm: int,
+    bf: int,
     interpret: bool = True,
 ) -> jax.Array:
     n, m, k = x.shape
     n2, k2, f = w.shape
     assert (n, k) == (n2, k2), f"shape mismatch {x.shape} @ {w.shape}"
-    bm, bf, bk = min(bm, m), min(bf, f), min(bk, k)
-    assert m % bm == 0 and f % bf == 0 and k % bk == 0, (
-        f"dims ({m},{k},{f}) must tile by ({bm},{bk},{bf})")
-    grid = (n, m // bm, f // bf, k // bk)
+    assert m % bm == 0 and f % bf == 0, (
+        f"dims ({m},{f}) must tile by ({bm},{bf})")
     return pl.pallas_call(
         _kernel,
-        grid=grid,
+        grid=(n, m // bm, f // bf),
         in_specs=[
-            pl.BlockSpec((1, bm, bk), lambda b, i, j, kk: (b, i, kk)),
-            pl.BlockSpec((1, bk, bf), lambda b, i, j, kk: (b, kk, j)),
+            pl.BlockSpec((1, bm, k), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((1, k, bf), lambda b, i, j: (b, 0, j)),
         ],
-        out_specs=pl.BlockSpec((1, bm, bf), lambda b, i, j, kk: (b, i, j)),
+        out_specs=pl.BlockSpec((1, bm, bf), lambda b, i, j: (b, i, j)),
         out_shape=jax.ShapeDtypeStruct((n, m, f), x.dtype),
-        scratch_shapes=[pltpu.VMEM((bm, bf), jnp.float32)],
         interpret=interpret,
     )(x, w)

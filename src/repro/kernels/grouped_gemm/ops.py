@@ -18,13 +18,20 @@ import jax.numpy as jnp
 from ...runtime.faults import maybe_fire
 from ...runtime.guard import DegradationWarning, kernel_log
 from .. import INTERPRET_GRID_LIMIT, interpret_mode
-from ..branch_gemm.ops import select_tiles
 from .kernel import grouped_gemm_pallas
 from .ref import grouped_gemm_ref
 
 
 def _round_up(x: int, mult: int) -> int:
     return -(-x // mult) * mult
+
+
+def _halve_to_divisor(dim: int, tile: int) -> int:
+    """The largest ``tile / 2**i`` (capped at ``dim``) that divides ``dim``."""
+    tile = min(tile, dim)
+    while dim % tile:
+        tile //= 2
+    return tile
 
 
 def grouped_gemm_parts(xs: list[jax.Array], w: jax.Array,
@@ -45,9 +52,9 @@ def grouped_gemm_parts(xs: list[jax.Array], w: jax.Array,
     if k % 128 or f % 128 or total == 0:
         return [grouped_gemm_ref(x, w[i:i + 1], (m,))
                 for i, (x, m) in enumerate(zip(xs, group_sizes))]
-    # F/K tiling follows branch_gemm's ONE tile-selection rule; only the
-    # row tile is ragged-specific (per-group padding picks it below)
-    _, bf, bk = select_tiles(8, k, f, 8, bf, bk)
+    # the F/K tiles halve from the defaults until they divide; the row tile
+    # is ragged-specific (per-group padding picks it below)
+    bf, bk = _halve_to_divisor(f, bf), _halve_to_divisor(k, bk)
     m_max = max(group_sizes)
     bm = min(bm, _round_up(m_max, 8))
     tile_group: list[int] = []

@@ -144,24 +144,75 @@ def test_run_sequential_honors_output_ids():
     assert sel[0].shape == (8, 64)
 
 
-def test_pick_gemm_route_estimate_matches_kernel_tiles():
+def _launched_grids(monkeypatch):
+    """Record the grid of every branch_gemm kernel launch."""
+    from repro.kernels.branch_gemm import ops
+
+    grids = []
+    real = ops.branch_gemm_pallas
+
+    def spy(x, w, bm, bf, interpret):
+        n, m, _ = x.shape
+        grids.append(n * (m // bm) * (w.shape[-1] // bf))
+        return real(x, w, bm=bm, bf=bf, interpret=interpret)
+
+    monkeypatch.setattr(ops, "branch_gemm_pallas", spy)
+    return grids
+
+
+def test_pick_gemm_route_estimate_matches_kernel_tiles(monkeypatch):
     """The interpret-mode grid estimate must count the grid the branch_gemm
-    wrapper actually launches (shared select_tiles), M included — the old
-    hardcoded k//512 divisor undercounted non-dividing K and ignored M."""
+    wrapper actually launches (shared tile rule), M included."""
     from repro.core.capture import _VMAP, _BRANCH_GEMM, _pick_gemm_route
-    from repro.kernels.branch_gemm.ops import select_tiles
+    from repro.kernels.branch_gemm.ops import branch_gemm
 
-    # K=640 halves down to bk=128 → 5 K-tiles; the old k//512 estimate saw 1
+    grids = _launched_grids(monkeypatch)
+    # K=640 (5 x 128) is taken whole: one grid step per branch, where the
+    # old halving rule split it into 5 K-tiles
     w = jnp.zeros((640, 128), jnp.float32)
-    bm, bf, bk = select_tiles(8, 640, 128)
-    assert (640 // bk) == 5
-    assert _pick_gemm_route(w, 16, "auto", m=8) == _VMAP       # 16·5 > 64
-    assert _pick_gemm_route(w, 8, "auto", m=8) == _BRANCH_GEMM  # 8·5 ≤ 64
+    branch_gemm(jnp.zeros((1, 8, 640), jnp.float32), w[None])
+    assert grids == [1]
+    assert _pick_gemm_route(w, 65, "auto", m=8) == _VMAP        # 65 > 64
+    assert _pick_gemm_route(w, 64, "auto", m=8) == _BRANCH_GEMM  # 64 ≤ 64
 
-    # M scales the grid too: 4 branches fit at m=512, not at m=4096
+    # M scales the grid too: 4 branches fit at m=512 (one row tile), not at
+    # m=131072, where VMEM bounds the row tile to 4096 rows (32 of them)
     w2 = jnp.zeros((128, 128), jnp.float32)
+    branch_gemm(jnp.zeros((1, 512, 128), jnp.float32), w2[None])
+    assert grids[-1] == 1
     assert _pick_gemm_route(w2, 4, "auto", m=512) == _BRANCH_GEMM
-    assert _pick_gemm_route(w2, 4, "auto", m=4096) == _VMAP
+    assert _pick_gemm_route(w2, 4, "auto", m=131072) == _VMAP
     # explicit kernel choice still wins
     assert _pick_gemm_route(w, 64, "pallas", m=4096) == _BRANCH_GEMM
     assert _pick_gemm_route(w2, 2, "vmap", m=8) == _VMAP
+
+
+def test_program_stats_counts_the_launched_branch_gemm_grid(monkeypatch):
+    """``branch_gemm_grid`` appears once the program has traced and equals
+    the grid steps the kernel wrapper launched for one call."""
+    from repro.core.graph import OpGraph, OpKind
+    from repro.core.profiler import gemm_cost
+    from repro.kernels.branch_gemm.ops import grid_steps, select_tiles
+
+    g = OpGraph("two_gemms")
+    x = g.add("x", OpKind.INPUT, out_shape=(2, 24, 640))
+    rng = np.random.default_rng(0)
+    for i in range(2):
+        w = jnp.asarray(rng.standard_normal((640, 384)) * 0.05, jnp.float32)
+        g.add(f"gemm{i}", OpKind.GEMM, [x], fn=lambda a, w: a @ w,
+              cost=gemm_cost(48, 640, 384, 4), fuse_sig=("gemm", 640, 384),
+              consts=(w,), payload="matmul")
+    exe = compile_plan(schedule(g, "opara", "opara"), gemm_kernel="pallas")
+    assert exe.program_stats()["n_branch_gemm"] == 1
+    assert "branch_gemm_grid" not in exe.program_stats()
+    grids = _launched_grids(monkeypatch)
+    x_val = jnp.asarray(rng.standard_normal((2, 24, 640)), jnp.float32)
+    got = exe({"x": x_val})
+    ref = run_sequential_uncompiled(g, {"x": x_val},
+                                    output_ids=exe.output_ids)
+    for a, b in zip(got, ref):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-4, atol=1e-4)
+    assert select_tiles(48, 640, 384, 4) == (48, 384)
+    assert grids == [grid_steps(2, 48, 640, 384, 4)] == [2]
+    assert exe.program_stats()["branch_gemm_grid"] == 2.0

@@ -49,14 +49,25 @@ def one_chip():
             jax.config.update("jax_enable_compilation_cache", cache_on)
 
 
-def _branch_gemm():
-    # the captured gate||up wave: two [512, 896] @ [896, 4864] branches
-    m = B * SEQ
-    bm, bf, bk = select_tiles(m, D_MODEL, D_FF)
-    fn = functools.partial(branch_gemm_pallas, bm=bm, bf=bf, bk=bk,
-                           interpret=False)
+def _stacked_gemm(f):
+    # two [2048, 896] @ [896, f] branches, the benchmark's batch 8 x 256,
+    # at the tiles the kernel's rule picks (the whole of K: 11.9 MiB of
+    # VMEM at f = 4864, under the default scoped limit)
+    m = 8 * 256
+    bm, bf = select_tiles(m, D_MODEL, f)
+    fn = functools.partial(branch_gemm_pallas, bm=bm, bf=bf, interpret=False)
     return fn, [((2, m, D_MODEL), jnp.bfloat16),
-                ((2, D_MODEL, D_FF), jnp.bfloat16)]
+                ((2, D_MODEL, f), jnp.bfloat16)]
+
+
+def _branch_gemm():
+    # the captured gate||up wave
+    return _stacked_gemm(D_FF)
+
+
+def _branch_gemm_kv():
+    # the captured k||v wave: F = 2 KV heads x head dim 64
+    return _stacked_gemm(KVH * D)
 
 
 def _flash_attention():
@@ -88,8 +99,9 @@ def _paged_decode():
                 ((B,), jnp.int32)]
 
 
-@pytest.mark.parametrize("case", [_branch_gemm, _flash_attention,
-                                  _decode_attention, _paged_decode],
+@pytest.mark.parametrize("case", [_branch_gemm, _branch_gemm_kv,
+                                  _flash_attention, _decode_attention,
+                                  _paged_decode],
                          ids=lambda c: c.__name__.lstrip("_"))
 def test_kernel_compiles_for_v5e(one_chip, case):
     fn, args = case()

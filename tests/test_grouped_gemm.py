@@ -89,6 +89,29 @@ def test_grouped_gemm_non_tileable_falls_back_to_ref():
     _assert_close(got, grouped_gemm_ref(x, w, sizes), 1e-5, 1e-5)
 
 
+@pytest.mark.parametrize("k,f,tiles", [(640, 384, (8, 128, 128)),
+                                       (1024, 256, (8, 128, 512))])
+def test_grouped_gemm_keeps_its_halving_tiles(monkeypatch, k, f, tiles):
+    """The ragged kernel keeps its own tiles — F and K halve from 128 and
+    512 until they divide — apart from branch_gemm's VMEM-sized rule."""
+    from repro.kernels.grouped_gemm import ops
+
+    launched = []
+
+    def spy(xp, w, tile_group, bm, bf, bk, interpret):
+        launched.append((bm, bf, bk))
+        return grouped_gemm_pallas(xp, w, tile_group, bm=bm, bf=bf, bk=bk,
+                                   interpret=interpret)
+
+    monkeypatch.setattr(ops, "grouped_gemm_pallas", spy)
+    sizes = (3, 5)
+    x = _rand((sum(sizes), k), jnp.float32)
+    w = _rand((len(sizes), k, f), jnp.float32)
+    _assert_close(grouped_gemm(x, w, sizes), grouped_gemm_ref(x, w, sizes),
+                  1e-4, 1e-4)
+    assert launched == [tiles]
+
+
 def test_grouped_gemm_all_empty():
     x = jnp.zeros((0, 128), jnp.float32)
     w = _rand((3, 128, 128), jnp.float32)

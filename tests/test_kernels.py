@@ -30,6 +30,71 @@ def test_branch_gemm(n, m, k, f, dtype):
     _assert_close(branch_gemm(x, w), branch_gemm_ref(x, w), tol, tol)
 
 
+# K = 640 and F = 384 are multiples of 128 but not powers of two: the rule
+# takes K whole and F = 3 x 128 whole, where the old halving rule fell to
+# 128-wide tiles; the explicit tiles split M and F
+@pytest.mark.parametrize("n,m,k,f,tiles", [
+    (2, 24, 640, 384, None),
+    (2, 16, 640, 384, (8, 128)),
+    (3, 40, 384, 640, (8, 128)),
+])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_branch_gemm_tiles_match_ref(n, m, k, f, tiles, dtype):
+    from repro.kernels.branch_gemm.kernel import branch_gemm_pallas
+    from repro.kernels.branch_gemm.ops import select_tiles
+    from repro.kernels.branch_gemm.ref import branch_gemm_ref
+    x = _rand((n, m, k), dtype, 0.1)
+    w = _rand((n, k, f), dtype, 0.1)
+    if tiles is None:
+        tiles = select_tiles(m, k, f, x.dtype.itemsize)
+        assert tiles == (m, f)
+    bm, bf = tiles
+    got = branch_gemm_pallas(x, w, bm=bm, bf=bf, interpret=True)
+    assert got.dtype == x.dtype
+    tol = 2e-2 if dtype == jnp.bfloat16 else 1e-5
+    _assert_close(got, branch_gemm_ref(x, w), tol, tol)
+
+
+@pytest.mark.parametrize("f,tiles", [(4864, (2048, 256)), (128, (2048, 128))])
+def test_branch_gemm_tile_rule_at_qwen2_widths(f, tiles):
+    """qwen2-0.5b's stacked groups at batch 8 x 256 (gate||up, k||v): blocks
+    of the whole of K = 896, double-buffered, plus the fp32 result fit the
+    v5e's default scoped VMEM; the next column tile up does not."""
+    from repro.kernels.branch_gemm.ops import (VMEM_BUDGET, grid_steps,
+                                               select_tiles, vmem_bytes)
+    m, k = 8 * 256, 896
+    assert VMEM_BUDGET == 16 * 1024 * 1024
+    bm, bf = select_tiles(m, k, f)
+    assert (bm, bf) == tiles
+    assert m % bm == 0 and bm % 8 == 0 and f % bf == 0 and bf % 128 == 0
+    assert (vmem_bytes(bm, bf, k, 2)
+            == 2 * 2 * (bm * k + k * bf + bm * bf) + 4 * bm * bf)
+    assert vmem_bytes(bm, bf, k, 2) <= VMEM_BUDGET
+    # one grid step per output block: no K axis
+    assert grid_steps(2, m, k, f) == 2 * (m // bm) * (f // bf)
+    if f == 4864:   # the next column tile dividing F is 2432 = 19 x 128
+        assert vmem_bytes(bm, 2432, k, 2) > VMEM_BUDGET
+
+
+def test_branch_gemm_too_deep_for_vmem_runs_the_reference(monkeypatch):
+    """Off the lattice, or with K so deep that no blocks of the whole of K
+    fit VMEM, the rule picks no tiles and the wrapper runs the einsum ref."""
+    from repro.kernels.branch_gemm import ops
+    assert ops.select_tiles(12, 128, 128) is None   # M not a multiple of 8
+    assert ops.select_tiles(8, 100, 128) is None    # K not a multiple of 128
+    # K = 2^16 at 4-byte items: even 8 x 128 blocks overflow the budget
+    k = 2 ** 16
+    assert ops.vmem_bytes(8, 128, k, 4) > ops.VMEM_BUDGET
+    assert ops.select_tiles(8, k, 128, 4) is None
+    assert ops.grid_steps(2, 8, k, 128, 4) == 0
+    assert ops.select_tiles(8, k, 128, 2) is None
+    assert ops.select_tiles(8, k // 4, 128, 2) == (8, 128)
+    monkeypatch.setattr(ops, "branch_gemm_pallas", None)   # never launched
+    x = _rand((1, 8, k), jnp.float32, 0.01)
+    w = _rand((1, k, 128), jnp.float32, 0.01)
+    _assert_close(ops.branch_gemm(x, w), ops.branch_gemm_ref(x, w), 0, 0)
+
+
 # --------------------------------------------------------- flash_attention
 @pytest.mark.parametrize("s,t,h,kvh,d", [(128, 128, 4, 2, 32),
                                          (256, 256, 4, 4, 64),
