@@ -17,6 +17,7 @@ from .base import (
     SHAPES,
     ShapeCell,
     SSMConfig,
+    YaRNConfig,
     cell_applicable,
 )
 
@@ -47,6 +48,6 @@ def get_config(arch: str, smoke: bool = False) -> ModelConfig:
 
 __all__ = [
     "FrontendConfig", "MLAConfig", "ModelConfig", "MoEConfig", "ParallelConfig",
-    "SHAPES", "ShapeCell", "SSMConfig", "cell_applicable",
+    "SHAPES", "ShapeCell", "SSMConfig", "YaRNConfig", "cell_applicable",
     "get_config", "list_archs",
 ]

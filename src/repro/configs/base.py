@@ -23,6 +23,32 @@ class MoEConfig:
     capacity_factor: float = 1.25
     router_aux_free: bool = False  # DeepSeek-V3 aux-loss-free bias balancing
     router_noise: float = 0.0
+    # DeepSeek-V3 group-limited routing: experts fall into ``n_group`` equal
+    # groups and a token picks its experts from its ``topk_group`` best
+    n_group: int = 1
+    topk_group: int = 1
+    routed_scale: float = 1.0     # routed_scaling_factor on the routed weights
+    # expert parallelism: this layer holds routed experts
+    # [expert_offset, expert_offset + experts_held) of n_experts; None = all
+    experts_held: int | None = None
+    expert_offset: int = 0
+
+    @property
+    def n_local(self) -> int:
+        """Routed experts whose weights this layer holds."""
+        return self.n_experts if self.experts_held is None else self.experts_held
+
+
+@dataclasses.dataclass(frozen=True)
+class YaRNConfig:
+    """YaRN rotary scaling (DeepSeek-V3's ``rope_scaling``, type yarn)."""
+
+    factor: float = 40.0
+    original_max_position_embeddings: int = 4096
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 1.0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -71,10 +97,12 @@ class ModelConfig:
     act: str = "swiglu"           # swiglu | gelu
     rope_theta: float = 1e4
     rope: bool = True
+    rope_scaling: YaRNConfig | None = None
     max_seq_len: int = 131072
     tie_embeddings: bool = False
     residual_scale: float = 1.0   # MiniCPM depth-scaled residuals
     moe: MoEConfig | None = None
+    dense_prefix: int = 0         # leading layers with a dense FFN (MoE stacks)
     mla: MLAConfig | None = None
     ssm: SSMConfig | None = None
     window: int | None = None     # sliding-window size (hybrid/window layers)
@@ -123,7 +151,7 @@ class ModelConfig:
             if self.moe is not None:
                 e = self.moe
                 per_layer += d * e.n_experts  # router
-                per_layer += (e.n_experts + e.n_shared) * 3 * d * e.d_expert
+                per_layer += (e.n_local + e.n_shared) * 3 * d * e.d_expert
             else:
                 mult = 3 if self.act == "swiglu" else 2
                 per_layer += mult * d * self.d_ff
@@ -139,7 +167,7 @@ class ModelConfig:
             return self.n_params()
         e = self.moe
         d, L = self.d_model, self.n_layers
-        full_expert = e.n_experts * 3 * d * e.d_expert
+        full_expert = e.n_local * 3 * d * e.d_expert
         act_expert = (e.top_k + e.n_shared) * 3 * d * e.d_expert
         return int(self.n_params() - L * full_expert + L * act_expert
                    - (L * e.n_shared * 3 * d * e.d_expert))

@@ -88,7 +88,7 @@ def _block_record(cfg: ModelConfig, cell: ShapeCell, mesh, kind: str,
             def inner(p, x):
                 y, _, aux = block_seq(p, x, cfg, positions, jnp.int32(win),
                                       None, False, kind)
-                return (y.astype(jnp.float32).mean() + aux).sum()
+                return (y.astype(jnp.float32).mean() + aux["aux_loss"]).sum()
             return jax.grad(inner, argnums=(0, 1))(p, x)
         args = (p_shapes, x_sds)
         in_sh = (p_sh, None)

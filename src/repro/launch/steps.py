@@ -56,7 +56,7 @@ def make_prefill_step(model: Model, cell: ShapeCell) -> Callable:
     cache_len = cell.seq_len + model.cfg.meta_tokens
 
     def prefill_step(params, inputs):
-        logits, caches = model.prefill(params, inputs, cache_len=cache_len)
+        logits, caches, _ = model.prefill(params, inputs, cache_len=cache_len)
         return logits, caches
 
     return prefill_step

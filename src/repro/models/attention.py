@@ -17,7 +17,7 @@ import jax.numpy as jnp
 
 from ..configs.base import MLAConfig, ModelConfig
 from ..utils import shard
-from .layers import apply_rope, init_linear, linear
+from .layers import apply_rope, init_linear, linear, yarn_mscale
 
 NEG_INF = -1e30
 
@@ -72,6 +72,7 @@ def _sdpa(q, k, v, mask, use_kernels: bool = False, scale: float | None = None):
 # -- chunked flash-structured attention (pure jnp, production shapes) ---------
 
 _CHUNK_THRESHOLD = 1 << 22        # s*t above which we never materialize [S,T]
+MLA_Q_CHUNK = 512                 # query rows per chunk of MLA prefill
 # roofline hook: "single" forces one chunk (scan trip=1) so cost_analysis
 # counts attention exactly (launch/roofline.py); None = production chunking.
 _CHUNK_OVERRIDE: str | None = None
@@ -314,8 +315,8 @@ def gqa_prefill(p, x, cfg: ModelConfig, positions, window=None, use_kernels=Fals
     k = shard(k, "batch", "seq", "kv_heads", None)
     v = shard(v, "batch", "seq", "kv_heads", None)
     if cfg.rope:
-        q = apply_rope(q, positions, cfg.rope_theta)
-        k = apply_rope(k, positions, cfg.rope_theta)
+        q = apply_rope(q, positions, cfg.rope_theta, cfg.rope_scaling)
+        k = apply_rope(k, positions, cfg.rope_theta, cfg.rope_scaling)
     if use_kernels:
         from ..kernels.flash_attention.ops import flash_attention_tpu_or_ref
         out = flash_attention_tpu_or_ref(q, k, v, None)
@@ -341,8 +342,8 @@ def gqa_decode(p, x, cache_kv, pos, cfg: ModelConfig, window=None, use_kernels=F
     k = linear(p["wk"], x).reshape(b, 1, kvh, hd)
     v = linear(p["wv"], x).reshape(b, 1, kvh, hd)
     if cfg.rope:
-        q = apply_rope(q, pos[:, None], cfg.rope_theta)
-        k = apply_rope(k, pos[:, None], cfg.rope_theta)
+        q = apply_rope(q, pos[:, None], cfg.rope_theta, cfg.rope_scaling)
+        k = apply_rope(k, pos[:, None], cfg.rope_theta, cfg.rope_scaling)
     from ..flags import cache_update_mode
     if cache_update_mode() == "scatter":
         # §Perf O1: scatter writes ONE slot per sequence (aliasable in-place
@@ -429,9 +430,21 @@ def _mla_qkv(p, x, cfg: ModelConfig, positions):
     kv = linear(p["wkv_a"], x)
     c_kv, k_rope = jnp.split(kv, [m.kv_lora_rank], axis=-1)
     c_kv = rmsnorm(p["kv_norm"], c_kv)                  # [B,S,rank]
-    q_rope = apply_rope(q_rope, positions, cfg.rope_theta)
-    k_rope = apply_rope(k_rope[:, :, None, :], positions, cfg.rope_theta)[:, :, 0]
+    q_rope = apply_rope(q_rope, positions, cfg.rope_theta, cfg.rope_scaling)
+    k_rope = apply_rope(k_rope[:, :, None, :], positions, cfg.rope_theta,
+                        cfg.rope_scaling)[:, :, 0]
     return q_nope, q_rope, c_kv, k_rope
+
+
+def mla_softmax_scale(cfg: ModelConfig) -> float:
+    """1/sqrt(qk head dim), times mscale(mscale_all_dim)^2 under YaRN (the
+    published DeepSeek attention's softmax scale)."""
+    m = cfg.mla
+    scale = (m.qk_nope_head_dim + m.qk_rope_head_dim) ** -0.5
+    ys = cfg.rope_scaling
+    if ys is not None and ys.mscale_all_dim:
+        scale *= yarn_mscale(ys.factor, ys.mscale_all_dim) ** 2
+    return scale
 
 
 def mla_attention(p, q_nope, q_rope, c_kv, k_rope, cfg: ModelConfig,
@@ -448,21 +461,25 @@ def mla_attention(p, q_nope, q_rope, c_kv, k_rope, cfg: ModelConfig,
     m = cfg.mla
     b, s, h, _ = q_nope.shape
     rank = m.kv_lora_rank
-    wk_b = p["wk_b"]["w"].reshape(rank, h, m.qk_nope_head_dim)
-    q_lat = jnp.einsum("bshd,rhd->bshr", q_nope, wk_b,
-                       preferred_element_type=jnp.float32).astype(q_nope.dtype)
-    q_cat = jnp.concatenate([q_lat, q_rope], axis=-1)       # [B,S,H,rank+rope]
-    k_cat = jnp.concatenate([c_kv, k_rope], axis=-1)[:, :, None, :]
-    v_lat = c_kv[:, :, None, :]                             # [B,T,1,rank]
-    scale = (m.qk_nope_head_dim + m.qk_rope_head_dim) ** -0.5
-    if chunked:
-        lat = chunked_attention(q_cat, k_cat, v_lat, causal=True, window=None,
-                                scale=scale)
-    else:
-        lat = _sdpa(q_cat, k_cat, v_lat, mask, scale=scale)  # [B,S,H,rank]
-    wv_b = p["wv_b"]["w"].reshape(rank, h, m.v_head_dim)
-    out = jnp.einsum("bshr,rhd->bshd", lat, wv_b,
-                     preferred_element_type=jnp.float32).astype(c_kv.dtype)
+    scale = mla_softmax_scale(cfg)
+    with jax.named_scope("latent_attention"):
+        wk_b = p["wk_b"]["w"].reshape(rank, h, m.qk_nope_head_dim)
+        q_lat = jnp.einsum("bshd,rhd->bshr", q_nope, wk_b,
+                           preferred_element_type=jnp.float32).astype(q_nope.dtype)
+        q_cat = jnp.concatenate([q_lat, q_rope], axis=-1)   # [B,S,H,rank+rope]
+        k_cat = jnp.concatenate([c_kv, k_rope], axis=-1)[:, :, None, :]
+        v_lat = c_kv[:, :, None, :]                         # [B,T,1,rank]
+        if chunked:
+            # 128 heads x 576 dims: query chunks of 512 keep one chunk's
+            # fp32 scores (H x 512 x 2048) near half a GB
+            lat = chunked_attention(q_cat, k_cat, v_lat, causal=True,
+                                    window=None, scale=scale,
+                                    q_chunk=MLA_Q_CHUNK)
+        else:
+            lat = _sdpa(q_cat, k_cat, v_lat, mask, scale=scale)  # [B,S,H,rank]
+        wv_b = p["wv_b"]["w"].reshape(rank, h, m.v_head_dim)
+        out = jnp.einsum("bshr,rhd->bshd", lat, wv_b,
+                         preferred_element_type=jnp.float32).astype(c_kv.dtype)
     return linear(p["wo"], out.reshape(b, s, h * m.v_head_dim))
 
 
@@ -534,8 +551,8 @@ def gqa_paged_decode(p, x, pages, block_tables, pos, cfg: ModelConfig,
     k = linear(p["wk"], x).reshape(b, 1, kvh, hd)
     v = linear(p["wv"], x).reshape(b, 1, kvh, hd)
     if cfg.rope:
-        q = apply_rope(q, pos[:, None], cfg.rope_theta)
-        k = apply_rope(k, pos[:, None], cfg.rope_theta)
+        q = apply_rope(q, pos[:, None], cfg.rope_theta, cfg.rope_scaling)
+        k = apply_rope(k, pos[:, None], cfg.rope_theta, cfg.rope_scaling)
     rows = jnp.arange(b)
     page = block_tables[rows, pos // ps]                # [B] physical pages
     off = pos % ps
@@ -578,24 +595,26 @@ def mla_paged_decode(p, x, pages, block_tables, pos, cfg: ModelConfig,
         kpe_pages = kpe_pages.at[page, off].set(
             r_new[:, 0].astype(kpe_pages.dtype))
     lengths = (pos + 1).astype(jnp.int32)
-    scale = (m.qk_nope_head_dim + m.qk_rope_head_dim) ** -0.5
-    wk_b = p["wk_b"]["w"].reshape(rank, h, m.qk_nope_head_dim)
-    if use_kernels:
-        from ..kernels.paged_decode.ops import paged_mla_decode_attention
-        lat = paged_mla_decode_attention(q_nope[:, 0], q_rope[:, 0], ckv_pages,
-                                         kpe_pages, wk_b, block_tables,
-                                         lengths, scale)
-    else:
-        from ..kernels.paged_decode.ref import paged_decode_attention_ref
-        q_lat = jnp.einsum("bhd,rhd->bhr", q_nope[:, 0], wk_b,
-                           preferred_element_type=jnp.float32).astype(x.dtype)
-        q_cat = jnp.concatenate([q_lat, q_rope[:, 0]], axis=-1)
-        k_cat = jnp.concatenate([ckv_pages, kpe_pages], axis=-1)[:, :, None, :]
-        lat = paged_decode_attention_ref(q_cat, k_cat, ckv_pages[:, :, None, :],
-                                         block_tables, lengths, None, scale)
-    wv_b = p["wv_b"]["w"].reshape(rank, h, m.v_head_dim)
-    out = jnp.einsum("bhr,rhd->bhd", lat, wv_b,
-                     preferred_element_type=jnp.float32).astype(x.dtype)
+    scale = mla_softmax_scale(cfg)
+    with jax.named_scope("latent_attention"):
+        wk_b = p["wk_b"]["w"].reshape(rank, h, m.qk_nope_head_dim)
+        if use_kernels:
+            from ..kernels.paged_decode.ops import paged_mla_decode_attention
+            lat = paged_mla_decode_attention(q_nope[:, 0], q_rope[:, 0],
+                                             ckv_pages, kpe_pages, wk_b,
+                                             block_tables, lengths, scale)
+        else:
+            from ..kernels.paged_decode.ref import paged_decode_attention_ref
+            q_lat = jnp.einsum("bhd,rhd->bhr", q_nope[:, 0], wk_b,
+                               preferred_element_type=jnp.float32).astype(x.dtype)
+            q_cat = jnp.concatenate([q_lat, q_rope[:, 0]], axis=-1)
+            k_cat = jnp.concatenate([ckv_pages, kpe_pages], axis=-1)[:, :, None, :]
+            lat = paged_decode_attention_ref(q_cat, k_cat,
+                                             ckv_pages[:, :, None, :],
+                                             block_tables, lengths, None, scale)
+        wv_b = p["wv_b"]["w"].reshape(rank, h, m.v_head_dim)
+        out = jnp.einsum("bhr,rhd->bhd", lat, wv_b,
+                         preferred_element_type=jnp.float32).astype(x.dtype)
     y = linear(p["wo"], out.reshape(b, 1, h * m.v_head_dim))
     return y, (ckv_pages, kpe_pages)
 

@@ -2,10 +2,16 @@
 
 MoE uses static-capacity dense dispatch (TPU-friendly: one-hot einsum
 scatter/gather, no data-dependent shapes) with:
-  * softmax top-k routing + optional DeepSeek-V3 aux-loss-free bias balancing,
+  * softmax top-k routing + optional DeepSeek-V3 aux-loss-free bias balancing
+    and group-limited selection,
   * shared (always-on) experts,
   * expert sharding over the ``expert`` logical axis (EP),
   * optional Pallas grouped-GEMM kernel for the expert compute.
+
+A layer told which experts it holds (``MoEConfig.experts_held``) is one
+chip's share under expert parallelism: it routes over every expert and
+computes, without dropping any, the (token, expert) pairs of the experts
+it holds (:func:`moe_ffn_share`).
 
 The per-expert FFN branches are exactly the "parallelizable operators" Opara
 schedules; the capacity-dense formulation IS the wave-fused execution of all
@@ -59,15 +65,18 @@ def init_moe(key, cfg: ModelConfig):
     p = {
         "router": {
             "w": (jax.random.normal(ks[0], (d, e.n_experts), jnp.float32) * d ** -0.5),
-            "bias": jnp.zeros((e.n_experts,), jnp.float32),  # aux-free balancing
+            # aux-free balancing: the correction bias added to the scores
+            # for selection only
+            "b": jnp.zeros((e.n_experts,), jnp.float32),
         },
-        # stacked expert weights [E, ...] — sharded over the expert axis
+        # stacked weights [E, ...] of the experts held — sharded over the
+        # expert axis
         "experts": {
-            "gate": (jax.random.normal(ks[1], (e.n_experts, d, e.d_expert), jnp.float32)
+            "gate": (jax.random.normal(ks[1], (e.n_local, d, e.d_expert), jnp.float32)
                      * d ** -0.5).astype(dtype),
-            "up": (jax.random.normal(ks[2], (e.n_experts, d, e.d_expert), jnp.float32)
+            "up": (jax.random.normal(ks[2], (e.n_local, d, e.d_expert), jnp.float32)
                    * d ** -0.5).astype(dtype),
-            "down": (jax.random.normal(ks[3], (e.n_experts, e.d_expert, d), jnp.float32)
+            "down": (jax.random.normal(ks[3], (e.n_local, e.d_expert, d), jnp.float32)
                      * e.d_expert ** -0.5).astype(dtype),
         },
     }
@@ -76,22 +85,42 @@ def init_moe(key, cfg: ModelConfig):
     return p
 
 
+def _group_limit(select, e):
+    """DeepSeek-V3 group-limited selection: a group scores the sum of its
+    two best selection scores; experts outside the ``topk_group`` best
+    groups are masked to -inf."""
+    n = select.shape[0]
+    groups = select.reshape(n, e.n_group, -1)                    # [N,G,E/G]
+    group_score = jax.lax.top_k(groups, 2)[0].sum(-1)            # [N,G]
+    _, keep_idx = jax.lax.top_k(group_score, e.topk_group)       # [N,tg]
+    keep = jnp.zeros((n, e.n_group), bool).at[
+        jnp.arange(n)[:, None], keep_idx].set(True)
+    return jnp.where(keep[:, :, None], groups, -jnp.inf).reshape(n, -1)
+
+
 def route(p_router, x, e, rng=None):
     """Top-k routing. Returns (weights [N,k], experts [N,k], aux metrics).
 
-    DeepSeek-V3 aux-loss-free: selection uses logits + per-expert bias; the
-    combine weights use the un-biased scores.  The bias is updated outside
-    the step (optimizer hook) toward load balance.
+    DeepSeek-V3 aux-loss-free: scores are sigmoid(x W) in float32 and
+    selection uses scores + the per-expert correction bias ``b`` (limited
+    to the ``topk_group`` best of ``n_group`` groups); the combine weights
+    are the un-biased scores of the chosen experts, normalised, times
+    ``routed_scale``.  The bias is updated outside the step (optimizer
+    hook) toward load balance.
     """
     n = x.shape[0]
     logits = jnp.einsum("nd,de->ne", x.astype(jnp.float32), p_router["w"])
     scores = jax.nn.sigmoid(logits) if e.router_aux_free else jax.nn.softmax(logits, -1)
-    select = scores + p_router["bias"][None, :] if e.router_aux_free else scores
+    select = scores + p_router["b"][None, :] if e.router_aux_free else scores
     if rng is not None and e.router_noise > 0:
         select = select + jax.random.normal(rng, select.shape) * e.router_noise
+    if e.n_group > 1:
+        select = _group_limit(select, e)
     _, top_idx = jax.lax.top_k(select, e.top_k)                  # [N,k]
     top_w = jnp.take_along_axis(scores, top_idx, axis=-1)        # [N,k]
     top_w = top_w / jnp.clip(top_w.sum(-1, keepdims=True), 1e-9)
+    if e.routed_scale != 1.0:
+        top_w = top_w * e.routed_scale
     # load-balance metrics (Switch-style): fraction per expert
     counts = jnp.zeros((e.n_experts,), jnp.float32).at[top_idx.reshape(-1)].add(1.0)
     load = counts / jnp.maximum(counts.sum(), 1.0)
@@ -104,12 +133,15 @@ def _capacity(n: int, e) -> int:
     return min(int(max(1, round(n * e.top_k / e.n_experts * e.capacity_factor))), n)
 
 
-def _expert_mlp(p_experts, buf, use_kernels: bool):
+def _expert_mlp(p_experts, buf, use_kernels: bool, rows=None):
     """Grouped expert GEMM: buf [E,C,d] → [E,C,d].  ONE fused kernel over the
-    expert axis — the horizontally-fused Opara wave (DESIGN.md §2)."""
+    expert axis — the horizontally-fused Opara wave (DESIGN.md §2).  With
+    ``rows`` [E] only each expert's first ``rows[e]`` rows are live: the
+    kernel skips the row tiles past them, whose output rows are undefined
+    (the reference computes them from the buffer's zero rows)."""
     if use_kernels:
         from ..kernels.moe_gemm.ops import moe_mlp_tpu_or_ref
-        return moe_mlp_tpu_or_ref(buf, p_experts)
+        return moe_mlp_tpu_or_ref(buf, p_experts, rows)
     h = jax.nn.silu(jnp.einsum("ecd,edf->ecf", buf, p_experts["gate"],
                                preferred_element_type=jnp.float32).astype(buf.dtype))
     h = h * jnp.einsum("ecd,edf->ecf", buf, p_experts["up"],
@@ -199,8 +231,67 @@ def moe_ffn_sort(p, x, cfg: ModelConfig, rng=None, use_kernels: bool = False):
     return y.reshape(b, s, d), aux
 
 
+def moe_ffn_share(p, x, cfg: ModelConfig, use_kernels: bool = False):
+    """One chip's expert-parallel share of an MoE layer, dropless.
+
+    Routes every token over all ``n_experts`` and computes every (token,
+    expert) pair whose expert lies in ``[expert_offset, expert_offset +
+    experts_held)``: each held expert's tokens are packed into its rows of
+    an [H, C, d] buffer (a token reaches an expert at most once, so C >= N
+    rows hold every pair), run through the grouped expert GEMM, which
+    skips the rows past each expert's pairs, and summed back with their
+    routing weights.  The output is the shared expert plus
+    the held experts' part; what the other experts add is computed by the
+    chips that hold them and is not part of this layer.
+
+    Returns (y, aux) with ``aux["moe_held"]`` = int32 [pairs that reached a
+    held expert, held experts that received at least one pair].
+    """
+    e = cfg.moe
+    b, s, d = x.shape
+    n = b * s
+    held = e.experts_held
+    xf = x.reshape(n, d)
+    # rows an expert: every token, padded to a multiple of 8 (of 128 past
+    # 128 tokens: a TPU row gather at an unaligned count compiles ~100x
+    # slower at d 7168)
+    cap = -(-n // 8) * 8 if n <= 128 else -(-n // 128) * 128
+    with jax.named_scope("router"):
+        top_w, top_idx, aux = route(p["router"], xf, e)
+        local = top_idx - e.expert_offset                              # [N,k]
+        pick = (local[:, :, None] == jnp.arange(held)) \
+            & ((local >= 0) & (local < held))[:, :, None]               # [N,k,H]
+        sel = jnp.pad(pick.any(1).T, ((0, 0), (0, cap - n)))           # [H,C]
+        w = jnp.pad(jnp.einsum("nkh,nk->hn", pick.astype(jnp.float32), top_w),
+                    ((0, 0), (0, cap - n)))                            # [H,C]
+        rows = sel.sum(1, dtype=jnp.int32)                             # [H]
+    with jax.named_scope("experts"):
+        # each expert's tokens first, in token order: token t sits at row
+        # slot[h, t] of expert h, and row j < rows[h] holds token order[h, j]
+        slot = jnp.cumsum(sel, axis=1, dtype=jnp.int32) - 1            # [H,C]
+        order = jnp.zeros((held, cap), jnp.int32).at[
+            jnp.arange(held)[:, None], jnp.where(sel, slot, cap)].set(
+            jnp.broadcast_to(jnp.arange(cap, dtype=jnp.int32), (held, cap)),
+            mode="drop")
+        xp = jnp.pad(xf, ((0, cap - n), (0, 0)))
+        live = (jnp.arange(cap) < rows[:, None])[..., None]
+        buf = jnp.where(live, xp[order], jnp.zeros((), xf.dtype))      # [H,C,d]
+        out = _expert_mlp(p["experts"], buf, use_kernels, rows)
+        back = jnp.take_along_axis(out, jnp.maximum(slot, 0)[..., None], axis=1)
+        back = jnp.where(sel[..., None], back, jnp.zeros((), back.dtype))
+        y = jnp.einsum("hn,hnd->nd", w, back.astype(jnp.float32))[:n]
+        y = y.astype(xf.dtype)
+    if e.n_shared:
+        with jax.named_scope("shared_expert"):
+            y = y + mlp(p["shared"], xf, "swiglu")
+    aux = dict(aux, moe_held=jnp.stack([rows.sum(), (rows > 0).sum(dtype=jnp.int32)]))
+    return y.reshape(b, s, d), aux
+
+
 def moe_ffn(p, x, cfg: ModelConfig, rng=None, use_kernels: bool = False):
     e = cfg.moe
+    if e.experts_held is not None:
+        return moe_ffn_share(p, x, cfg, use_kernels)
     if e.n_experts > 32:
         return moe_ffn_sort(p, x, cfg, rng, use_kernels)
     return moe_ffn_dense(p, x, cfg, rng, use_kernels)

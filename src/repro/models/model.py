@@ -2,7 +2,7 @@
 
     init(rng)                 → params
     loss(params, batch, rng)  → (loss, metrics)          [train shapes]
-    prefill(params, inputs)   → (last_logits, caches)    [prefill shapes]
+    prefill(params, inputs)   → (last_logits, caches, counters) [prefill shapes]
     decode(params, ...)       → (logits, caches)         [decode shapes]
     input_specs(cell)         → ShapeDtypeStruct pytree for the dry-run
     decode_state_specs(cell)  → cache ShapeDtypeStructs (no allocation)
@@ -46,9 +46,10 @@ class Model:
 
     def prefill(self, params, inputs: Mapping[str, Any], cache_len: int | None = None):
         if self.cfg.family == "encdec":
-            return ed.encdec_prefill(params, inputs["frames"], inputs["tokens"],
-                                     self.cfg, cache_len or inputs["tokens"].shape[1],
-                                     self.use_kernels)
+            logits, caches = ed.encdec_prefill(
+                params, inputs["frames"], inputs["tokens"], self.cfg,
+                cache_len or inputs["tokens"].shape[1], self.use_kernels)
+            return logits, caches, {}
         return tf.lm_prefill(params, inputs["tokens"], self.cfg, cache_len,
                              self.use_kernels, inputs.get("extra_embeds"))
 

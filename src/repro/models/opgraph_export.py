@@ -39,7 +39,7 @@ from ..core.profiler import (
     norm_cost,
     scan_cost,
 )
-from .attention import NEG_INF, causal_window_mask
+from .attention import NEG_INF, causal_window_mask, mla_softmax_scale
 from .export_costs import act_gemm_cost, stream_cost
 from .layers import apply_norm, apply_rope, gelu
 from .ssm import mamba_scan_ref, wkv_scan_ref
@@ -340,7 +340,8 @@ def _attn_stages(g, pre, q, k, v, b, s, t, nh, kvh, hd,
 # -- MLA (DeepSeek-style latent attention), decomposed ------------------------
 
 @functools.lru_cache(maxsize=None)
-def _make_mla_q_lat(nh: int, nope: int, rope: int, theta: float):
+def _make_mla_q_lat(nh: int, nope: int, rope: int, theta: float,
+                    scaling=None):
     """Absorbed query: rope the rope-part, fold W_kb into q_nope
     (mla_attention's q_lat einsum), emit head-major [B,H,S,rank+rope]."""
     def q_lat(qflat, wk_b):
@@ -348,7 +349,7 @@ def _make_mla_q_lat(nh: int, nope: int, rope: int, theta: float):
         q = qflat.reshape(b, s, nh, nope + rope)
         q_nope, q_rope = jnp.split(q, [nope], axis=-1)
         positions = jnp.broadcast_to(jnp.arange(s)[None], (b, s))
-        q_rope = apply_rope(q_rope, positions, theta)
+        q_rope = apply_rope(q_rope, positions, theta, scaling)
         wk = wk_b.reshape(-1, nh, nope)
         lat = jnp.einsum("bshd,rhd->bshr", q_nope, wk,
                          preferred_element_type=jnp.float32).astype(qflat.dtype)
@@ -357,7 +358,7 @@ def _make_mla_q_lat(nh: int, nope: int, rope: int, theta: float):
 
 
 @functools.lru_cache(maxsize=None)
-def _make_mla_kv_prep(rank: int, theta: float):
+def _make_mla_kv_prep(rank: int, theta: float, scaling=None):
     """Latent KV: rmsnorm the compressed part, rope the shared k_rope,
     concatenate — ONE latent head, head-major [B,1,S,rank+rope]."""
     def kv_prep(kv, scale):
@@ -365,7 +366,8 @@ def _make_mla_kv_prep(rank: int, theta: float):
         c_kv, k_rope = jnp.split(kv, [rank], axis=-1)
         c_kv = _rms({"scale": scale}, c_kv)
         positions = jnp.broadcast_to(jnp.arange(s)[None], (b, s))
-        k_rope = apply_rope(k_rope[:, :, None, :], positions, theta)[:, :, 0]
+        k_rope = apply_rope(k_rope[:, :, None, :], positions, theta,
+                            scaling)[:, :, 0]
         return jnp.concatenate([c_kv, k_rope], axis=-1)[:, None]
     return kv_prep
 
@@ -407,7 +409,8 @@ def _mla_block(g, cfg, n1, b, s, tag, attn_p):
     qb = _gemm_node(g, f"{tag}.wq_b", qn, attn_p and attn_p["wq_b"],
                     b * s, m.q_lora_rank, nh * qk_head)
     q_lat = g.add(f"{tag}.q_lat", OpKind.GEMM, [qb],
-                  fn=_make_mla_q_lat(nh, nope, rope, cfg.rope_theta)
+                  fn=_make_mla_q_lat(nh, nope, rope, cfg.rope_theta,
+                                     cfg.rope_scaling)
                   if with_fn else None,
                   cost=gemm_cost(b * s * nh, nope, rank),
                   fuse_sig=("qlat", s, nh, nope, rank),
@@ -416,7 +419,7 @@ def _mla_block(g, cfg, n1, b, s, tag, attn_p):
     kva = _gemm_node(g, f"{tag}.wkv_a", n1, attn_p and attn_p["wkv_a"],
                      b * s, d, rank + rope)
     kvp = g.add(f"{tag}.kv_prep", OpKind.NORM, [kva],
-                fn=_make_mla_kv_prep(rank, cfg.rope_theta)
+                fn=_make_mla_kv_prep(rank, cfg.rope_theta, cfg.rope_scaling)
                 if with_fn else None,
                 cost=norm_cost(b * s * (rank + rope)),
                 fuse_sig=("mlakv", s, rank, rope),
@@ -427,8 +430,8 @@ def _mla_block(g, cfg, n1, b, s, tag, attn_p):
                  cost=elementwise_cost(b * s * rank),
                  fuse_sig=("vlat", s, rank), out_shape=(b, 1, s, rank))
     mrg = _attn_core(g, f"{tag}.", q_lat, kvp, vlat, b, s, s, nh, 1,
-                     rank + rope, rank, scale=qk_head ** -0.5, causal=True,
-                     window=None, with_fn=with_fn)
+                     rank + rope, rank, scale=mla_softmax_scale(cfg),
+                     causal=True, window=None, with_fn=with_fn)
     aout = g.add(f"{tag}.attn_out", OpKind.GEMM, [mrg],
                  fn=_make_mla_out(nh, rank, m.v_head_dim)
                  if with_fn else None,

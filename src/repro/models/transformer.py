@@ -75,7 +75,8 @@ def init_block(key, cfg: ModelConfig, layer_kind: str):
 
 def block_seq(p, x, cfg: ModelConfig, positions, window, rng=None,
               use_kernels: bool = False, layer_kind: str = "dense"):
-    """Full-sequence block (train / prefill). Returns (x', cache, aux)."""
+    """Full-sequence block (train / prefill). Returns (x', cache, aux), aux
+    a dict: ``aux_loss``, and ``moe_held`` for an expert-share layer."""
     if layer_kind == "rwkv":
         state = rwkv_state_init(cfg, x.shape[0])
         y, tm_state = rwkv_time_mix_seq(p["time_mix"], apply_norm(p["norm1"], x, cfg.norm),
@@ -85,7 +86,7 @@ def block_seq(p, x, cfg: ModelConfig, positions, window, rng=None,
         y2, cm_x = rwkv_channel_mix(p["channel_mix"], h, state["cm_x"], cfg)
         x = x + y2
         cache = {"tm_x": tm_state[0], "tm_s": tm_state[1], "cm_x": cm_x}
-        return x, cache, jnp.float32(0.0)
+        return x, cache, {"aux_loss": jnp.float32(0.0)}
 
     h = apply_norm(p["norm1"], x, cfg.norm)
     attn_out, kv = attn_prefill(p["attn"], h, cfg, positions, window, use_kernels)
@@ -104,8 +105,11 @@ def block_seq(p, x, cfg: ModelConfig, positions, window, rng=None,
     cache: Any = kv
     if layer_kind == "hybrid":
         cache = {"kv": kv, "mamba_conv": m_state[0], "mamba_h": m_state[1]}
-    aux_loss = aux.get("aux_loss", jnp.float32(0.0)) if isinstance(aux, dict) else jnp.float32(0.0)
-    return x, cache, aux_loss
+    out = {"aux_loss": aux.get("aux_loss", jnp.float32(0.0))}
+    if "moe_held" in aux:
+        # an expert share also counts the pairs its experts computed
+        out["moe_held"] = aux["moe_held"]
+    return x, cache, out
 
 
 def block_step(p, x, cache, pos, cfg: ModelConfig, window, layer_kind: str = "dense",
@@ -160,10 +164,31 @@ def layer_kinds(cfg: ModelConfig) -> list[tuple[str, int]]:
 
 
 def cfg_dense_prefix(cfg: ModelConfig) -> int:
-    """DeepSeek-V3: first 3 layers dense; Kimi-K2: first layer dense."""
-    name = cfg.name.removesuffix("-smoke")
-    prefix = {"deepseek-v3-671b": 3, "kimi-k2-1t-a32b": 1}.get(name, 0)
-    return min(prefix, max(cfg.n_layers - 1, 0))
+    """Leading dense-FFN layers of an MoE stack (``cfg.dense_prefix``),
+    leaving at least one MoE layer."""
+    return min(cfg.dense_prefix, max(cfg.n_layers - 1, 0))
+
+
+def expert_share(cfg: ModelConfig) -> bool:
+    """MoE layers that hold a share of the routed experts: the aux dict of
+    their forward, prefill and paged decode holds ``moe_held``, int32
+    [pairs that reached a held expert, held experts that received one],
+    summed over the MoE layers."""
+    return cfg.moe is not None and cfg.moe.experts_held is not None
+
+
+def _aux0(kind: str, cfg: ModelConfig) -> dict:
+    """A stack's counters before its first layer: ``moe_held`` for an
+    expert share's MoE stack, nothing else (an empty dict adds no output
+    to a lowered program)."""
+    if kind == "moe" and expert_share(cfg):
+        return {"moe_held": jnp.zeros((2,), jnp.int32)}
+    return {}
+
+
+def _add_aux(a: dict, b: dict) -> dict:
+    """Two aux dicts summed key by key."""
+    return a | {k: a[k] + v if k in a else v for k, v in b.items()}
 
 
 def window_for_layer(cfg: ModelConfig, global_index: int) -> int:
@@ -208,15 +233,17 @@ def _scan_seq(stack_params, kind, windows, x, cfg, positions, rng, use_kernels,
         # training never reads the caches — dropping them here (instead of
         # trusting scan-DCE through jax.checkpoint) saves the full stacked
         # KV allocation.
-        return (x, aux + a), (cache if with_cache else None)
+        return (x, jax.tree_util.tree_map(jnp.add, aux, a)), \
+            (cache if with_cache else None)
 
     if remat:
         body = jax.checkpoint(body, prevent_cse=False)
     n = len(windows)
     keys = (jax.random.split(rng, n) if rng is not None
             else jnp.zeros((n,), jnp.uint32))
+    aux0 = {"aux_loss": jnp.float32(0.0), **_aux0(kind, cfg)}
     (x, aux), caches = jax.lax.scan(
-        body, (x, jnp.float32(0.0)), (stack_params, win_arr, keys))
+        body, (x, aux0), (stack_params, win_arr, keys))
     return x, aux, caches
 
 
@@ -284,19 +311,20 @@ def _embed_inputs(params, tokens, cfg: ModelConfig, extra_embeds=None):
 def lm_forward(params, tokens, cfg: ModelConfig, rng=None, use_kernels=False,
                remat=False, extra_embeds=None, with_cache: bool = True,
                with_logits: bool = True):
-    """Training/prefill forward → (logits [B,S',V] fp32, aux_loss, caches).
+    """Training/prefill forward → (logits [B,S',V] fp32, aux, caches), aux a
+    dict: ``aux_loss``, and ``moe_held`` summed where :func:`expert_share`.
     ``with_logits=False`` returns the final hidden states instead (used by
     the chunked-CE path that fuses the head matmul into the loss)."""
     x = _embed_inputs(params, tokens, cfg, extra_embeds)
     b, s, _ = x.shape
     positions = jnp.broadcast_to(jnp.arange(s)[None], (b, s))
-    aux_total = jnp.float32(0.0)
+    aux_total = {}
     caches = []
     for stack_params, (kind, _, windows) in zip(params["stacks"], stack_meta(cfg)):
         r = jax.random.fold_in(rng, len(caches)) if rng is not None else None
         x, aux, cache = _scan_seq(stack_params, kind, windows, x, cfg, positions,
                                   r, use_kernels, remat, with_cache)
-        aux_total += aux
+        aux_total = _add_aux(aux_total, aux)
         caches.append(cache)
     x = apply_norm(params["final_norm"], x, cfg.norm)
     if not with_logits:
@@ -329,6 +357,7 @@ def lm_loss(params, batch, cfg: ModelConfig, rng=None, use_kernels=False, remat=
         # align: logits predict the NEXT token; labels = tokens shifted by 1.
         prefix = logits.shape[1] - labels.shape[1]
         ce = softmax_xent(logits[:, prefix:], labels)
+    aux = aux["aux_loss"]
     loss = ce + 0.01 * aux
     metrics = {"ce": ce, "aux": aux}
     if cfg.mtp_heads:
@@ -362,12 +391,15 @@ def _mtp_loss(params, tokens, labels, cfg: ModelConfig):
 
 def lm_prefill(params, tokens, cfg: ModelConfig, cache_len: int | None = None,
                use_kernels=False, extra_embeds=None):
-    """Prefill → (last-token logits [B,V], caches padded to cache_len)."""
-    logits, _, caches = lm_forward(params, tokens, cfg, None, use_kernels,
-                                   False, extra_embeds)
+    """Prefill → (last-token logits [B,V], caches padded to cache_len,
+    counters): the counters hold ``moe_held`` where :func:`expert_share`,
+    else nothing."""
+    logits, aux, caches = lm_forward(params, tokens, cfg, None, use_kernels,
+                                     False, extra_embeds)
     if cache_len is not None and cfg.family not in ("ssm",):
         caches = [_pad_cache(c, cache_len, cfg) for c in caches]
-    return logits[:, -1], caches
+    aux.pop("aux_loss")
+    return logits[:, -1], caches, aux
 
 
 def _pad_cache(cache, length: int, cfg: ModelConfig):
@@ -420,28 +452,32 @@ def block_step_paged(p, x, pages, block_tables, pos, cfg: ModelConfig, window,
                                             pos, cfg, window, use_kernels)
     x = x + attn_out * cfg.residual_scale
     h2 = apply_norm(p["norm2"], x, cfg.norm)
+    aux = {}
     with jax.named_scope("mlp"):
         if layer_kind == "dense_prefix":
             from .ffn import mlp
             f_out = mlp(p["ffn"], h2, cfg.act)
         else:
-            f_out, _ = ffn(p["ffn"], h2, cfg, None, use_kernels)
+            f_out, aux = ffn(p["ffn"], h2, cfg, None, use_kernels)
     x = x + f_out * cfg.residual_scale
-    return x, new_pages
+    return x, new_pages, {k: aux[k] for k in ("moe_held",) if k in aux}
 
 
 def _scan_step_paged(stack_params, kind, windows, x, caches, block_tables, pos,
                      cfg, use_kernels=False):
+    """Returns (x, caches, counters summed over the stack)."""
     win_arr = jnp.array([w if w > 0 else (1 << 30) for w in windows], jnp.int32)
 
-    def body(x, xs):
+    def body(carry, xs):
+        x, aux = carry
         p_l, win_l, cache_l = xs
-        x, new_cache = block_step_paged(p_l, x, cache_l, block_tables, pos,
-                                        cfg, win_l, kind, use_kernels)
-        return x, new_cache
+        x, new_cache, a = block_step_paged(p_l, x, cache_l, block_tables, pos,
+                                           cfg, win_l, kind, use_kernels)
+        return (x, _add_aux(aux, a)), new_cache
 
-    x, new_caches = jax.lax.scan(body, x, (stack_params, win_arr, caches))
-    return x, new_caches
+    (x, aux), new_caches = jax.lax.scan(body, (x, _aux0(kind, cfg)),
+                                        (stack_params, win_arr, caches))
+    return x, new_caches, aux
 
 
 def init_paged_decode_caches(cfg: ModelConfig, num_pages: int, page_size: int):
@@ -462,20 +498,24 @@ def init_paged_decode_caches(cfg: ModelConfig, num_pages: int, page_size: int):
 def lm_paged_decode(params, token, caches, block_tables, pos,
                     cfg: ModelConfig, use_kernels=False):
     """One decode step over paged caches. token/pos: [B] int32;
-    block_tables: [B,MAXP] int32 (shared by every layer). → (logits, caches')."""
+    block_tables: [B,MAXP] int32 (shared by every layer). → (logits, caches',
+    counters): the counters hold ``moe_held`` where :func:`expert_share`,
+    else nothing."""
     x = embed(params["embed"], token[:, None])
     if cfg.meta_tokens:
         pos = pos + cfg.meta_tokens
     new_caches = []
+    aux = {}
     for stack_params, cache, (kind, _, windows) in zip(
             params["stacks"], caches, stack_meta(cfg)):
-        x, cache = _scan_step_paged(stack_params, kind, windows, x, cache,
-                                    block_tables, pos, cfg, use_kernels)
+        x, cache, a = _scan_step_paged(stack_params, kind, windows, x, cache,
+                                       block_tables, pos, cfg, use_kernels)
         new_caches.append(cache)
+        aux = _add_aux(aux, a)
     x = apply_norm(params["final_norm"], x, cfg.norm)
     head = params["embed"] if cfg.tie_embeddings else params["head"]
     logits = unembed(head, x)[:, 0]
-    return logits, new_caches
+    return logits, new_caches, aux
 
 
 def lm_decode(params, token, caches, pos, cfg: ModelConfig, use_kernels=False):

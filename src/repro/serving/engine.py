@@ -109,11 +109,39 @@ def _cached_paged_decode_fn(model: Model):
                 raise RuntimeError(
                     "paged decode step: model was garbage-collected; rebuild "
                     "the InferenceEngine with a live model")
-            return m.paged_decode(p, t, c, bt, pos)
+            logits, caches, counters = m.paged_decode(p, t, c, bt, pos)
+            # outputs first, the state last, as the dense step returns them
+            return (logits, counters), caches
 
         fn = jax.jit(_step)
         _PAGED_JIT_CACHE[model] = fn
     return fn
+
+
+def _batch_sampler():
+    """One jitted sampler over the whole decode batch, so a tick dispatches
+    once and reads its tokens back in one host read.  Row ``i`` draws what
+    ``sample_token`` draws on that row alone with key ``fold_in(sub, i)``
+    and the slot's temperature (0 is greedy), so the token streams are
+    those of sampling slot by slot.  ``drawn`` (static) is False when every
+    temperature is 0, and the program then takes the argmax alone.  Built
+    per engine, so it traces ``sample_token`` as it stands when the engine
+    is made."""
+
+    def sample(rng, logits, temps, drawn):
+        rng, sub = jax.random.split(rng)
+        tokens = sample_token(logits, sub)
+        if drawn:
+            keys = jax.vmap(jax.random.fold_in, (None, 0))(
+                sub, jnp.arange(logits.shape[0]))
+            scaled = logits / jnp.where(temps > 0, temps, 1.0).astype(
+                logits.dtype)[:, None]
+            t = jax.vmap(lambda k, row: sample_token(row[None], k, 1.0)[0])(
+                keys, scaled)
+            tokens = jnp.where(temps > 0, t, tokens)
+        return rng, tokens, jnp.isfinite(logits).all(axis=-1)
+
+    return jax.jit(sample, static_argnums=3)
 
 
 def _empty_tenant_stats() -> dict[str, int]:
@@ -222,6 +250,8 @@ class InferenceEngine:
             cache_len = max_len + self.cfg.meta_tokens
             self.caches = init_decode_caches(self.cfg, max_slots, cache_len)
         self._decode = _cached_decode_fn(model)
+        self._sample = _batch_sampler()
+        self._moe_held = {}          # the last decode tick's expert counts
         # Measured-mode Opara schedule of this engine's step graph, filled by
         # calibrate_schedule().  Engines for the same (model structure, batch
         # geometry, hardware) share one measured profile via the core
@@ -501,8 +531,11 @@ class InferenceEngine:
             if not free and len(self.admission) \
                     and self.admission_cfg.preemption:
                 out.extend(self._maybe_preempt())
+            self._moe_held = {}
             out.extend(self._paged_decode_tick() if self.paged
                        else self._decode_tick())
+            if self._moe_held:
+                sp.set(**self._moe_held)
         return out
 
     def _work_pending(self) -> bool:
@@ -634,17 +667,18 @@ class InferenceEngine:
         with tracing.span("engine.admit.prefill") as sp:
             before = tracing.compiles() if tracing.enabled() else None
             try:
-                logits, cache = self.model.prefill(
+                logits, cache, counters = self.model.prefill(
                     self.params, {"tokens": tokens}, cache_len=cache_len)
             except Exception as exc:
                 # a poisoned prompt must not take the engine down — the
                 # queue keeps draining and the decode batch never saw it
                 return None, f"prefill failed: {exc!r}"
-            finite = bool(np.isfinite(np.asarray(logits)).all())
+            logits_h, held = _read(logits, counters)
+            finite = bool(np.isfinite(logits_h).all())
             if before is not None:
                 # the eager prefill re-traces its scan on every admission
                 sp.set(tokens=len(tokens_list),
-                       **tracing.compiles_since(before))
+                       **tracing.compiles_since(before), **held)
         if not finite:
             return None, "prefill produced non-finite logits"
         with tracing.span("engine.admit.sample"):
@@ -734,8 +768,8 @@ class InferenceEngine:
             if faults is not None:
                 faults.fire("block_table_build")
             bt = jnp.asarray(self._block_table_array())
-            logits, caches = self._paged_decode(self.params, self.caches,
-                                                token, bt, pos)
+            (logits, _), caches = self._paged_decode(
+                self.params, self.caches, token, bt, pos)
             self.caches = caches
         except Exception as exc:
             logits = self._paged_fallback(exc)
@@ -860,7 +894,7 @@ class InferenceEngine:
         if err is None:
             try:
                 with tracing.span("engine.decode.dispatch"):
-                    logits, caches = self._paged_decode(
+                    (logits, counters), caches = self._paged_decode(
                         self.params, self.caches, token, bt, pos)
                     if faults is not None:
                         logits = faults.fire("decode_step", payload=logits)
@@ -868,6 +902,7 @@ class InferenceEngine:
             except Exception as exc:
                 err = exc
         if err is not None:
+            counters = {}
             logits = self._paged_fallback(err)
             if logits is None:
                 for i in still:
@@ -876,7 +911,7 @@ class InferenceEngine:
                     out.append(self._fail(
                         req, f"paged decode failed on both rungs: {err!r}"))
                 return out
-        out.extend(self._advance_slots(still, logits))
+        out.extend(self._advance_slots(still, logits, counters))
         return out
 
     def _paged_fallback(self, exc: Exception):
@@ -998,14 +1033,23 @@ class InferenceEngine:
                             "retrying the jitted decode step", warn=False)
         return self._advance_slots(active, logits)
 
-    def _advance_slots(self, active: list[int], logits) -> list[Request]:
-        """Per-slot sampling/completion tail shared by the dense and paged
-        decode ticks (identical rng discipline → identical token streams)."""
-        # the one place a decode tick waits for the device
+    def _advance_slots(self, active: list[int], logits,
+                       counters=None) -> list[Request]:
+        """Sampling/completion tail shared by the dense and paged decode
+        ticks (one batch sampler, identical rng discipline → identical
+        token streams).  ``counters`` are the step's, if it returned any:
+        read with the tokens, and kept for the tick's span."""
+        temps = np.zeros(len(self.slots), np.float32)
+        for i in active:
+            temps[i] = self.slots[i].temperature
+        # the one place a decode tick waits for the device: the batch's
+        # tokens and finiteness flags, with the step's counters
         with tracing.span("engine.decode.wait"):
-            finite_rows = np.isfinite(np.asarray(logits)).all(axis=-1)
+            self.rng, tokens, finite = self._sample(
+                self.rng, logits, temps, bool(temps.any()))
+            (tokens, finite_rows), self._moe_held = _read(
+                (tokens, finite), counters)
         with tracing.span("engine.decode.sample"):
-            self.rng, sub = jax.random.split(self.rng)
             finished: list[Request] = []
             for i in active:
                 req = self.slots[i]
@@ -1018,9 +1062,7 @@ class InferenceEngine:
                         req, "decode produced non-finite logits"))
                     self._clear_slot(i)
                     continue
-                t = int(sample_token(logits[i:i + 1],
-                                     jax.random.fold_in(sub, i),
-                                     req.temperature)[0])
+                t = int(tokens[i])
                 req.output.append(t)
                 self.pos[i] += 1
                 self.last_token[i] = t
@@ -1030,6 +1072,17 @@ class InferenceEngine:
                     finished.append(self._complete(req))
                     self._clear_slot(i)
         return finished
+
+
+def _read(out, counters):
+    """One host read of ``out`` (an array or a tuple of them) and of the
+    step's counters, if any: an expert share's ``moe_held`` comes back as
+    the span attributes ``moe_pairs_held`` and ``moe_experts_hit`` (else
+    the dict is empty)."""
+    if not counters:
+        return jax.device_get(out), {}
+    out_h, (pairs, hit) = jax.device_get((out, counters["moe_held"]))
+    return out_h, {"moe_pairs_held": int(pairs), "moe_experts_hit": int(hit)}
 
 
 def _splice(big, small, slot: int):

@@ -2,8 +2,9 @@
 
 Each kernel is compiled, not run, for one chip of a described (not
 attached) v5e at qwen2-0.5b's widths (14 query heads over 2 KV heads, head
-dim 64, d_model 896, d_ff 4864), and must lower to a Mosaic
-``tpu_custom_call``.  This catches what interpret mode cannot: block
+dim 64, d_model 896, d_ff 4864), or at DeepSeek-V3's (d_model 7168, 8
+held experts of width 2048, 128 latent heads over a 512 + 64 cache), and
+must lower to a Mosaic ``tpu_custom_call``.  This catches what interpret mode cannot: block
 shapes the TPU lowering refuses, and VMEM over-use.
 
 The topology is described only inside the module fixture (loading the TPU
@@ -20,6 +21,8 @@ from repro.kernels.branch_gemm.kernel import branch_gemm_pallas
 from repro.kernels.branch_gemm.ops import select_tiles
 from repro.kernels.decode_attention.kernel import decode_attention_pallas
 from repro.kernels.flash_attention.kernel import flash_attention_pallas
+from repro.kernels.moe_gemm.kernel import moe_mlp_pallas
+from repro.kernels.moe_gemm.ops import select_tiles as moe_tiles
 from repro.kernels.paged_decode.kernel import paged_decode_attention_pallas
 
 B, H, KVH, D, D_MODEL, D_FF = 4, 14, 2, 64, 896, 4864
@@ -99,9 +102,45 @@ def _paged_decode():
                 ((B,), jnp.int32)]
 
 
+def _moe_gemm(rows):
+    # the held experts' SwiGLU at the tiles the rule picks for ``rows``
+    # tokens a step (the kernel's blocks within the 16 MiB scoped VMEM)
+    e, d, f = 8, 7168, 2048
+    bc, bf = moe_tiles(rows, d, f)
+    c = -(-rows // bc) * bc
+    fn = functools.partial(moe_mlp_pallas, bc=bc, bf=bf, interpret=False)
+    return fn, [((e, c, d), jnp.bfloat16), ((e, d, f), jnp.bfloat16),
+                ((e, d, f), jnp.bfloat16), ((e, f, d), jnp.bfloat16),
+                ((e,), jnp.int32)]
+
+
+def _moe_gemm_decode():
+    return _moe_gemm(16)
+
+
+def _moe_gemm_prefill():
+    return _moe_gemm(8192)
+
+
+def _paged_mla_decode():
+    # the absorbed latent attention: 16 rows, 128 heads over one latent KV
+    # head, Dk 512 + 64, Dv 512, 73 pages of 128
+    b, h, maxp = 16, 128, 73
+    pages = 1 + b * maxp
+    fn = functools.partial(paged_decode_attention_pallas,
+                           scale=192 ** -0.5, interpret=False)
+    return fn, [((b, h, 576), jnp.bfloat16),
+                ((pages, 1, PAGE, 576), jnp.bfloat16),
+                ((pages, 1, PAGE, 512), jnp.bfloat16),
+                ((b * maxp,), jnp.int32),
+                ((b,), jnp.int32),
+                ((b,), jnp.int32)]
+
+
 @pytest.mark.parametrize("case", [_branch_gemm, _branch_gemm_kv,
                                   _flash_attention, _decode_attention,
-                                  _paged_decode],
+                                  _paged_decode, _moe_gemm_decode,
+                                  _moe_gemm_prefill, _paged_mla_decode],
                          ids=lambda c: c.__name__.lstrip("_"))
 def test_kernel_compiles_for_v5e(one_chip, case):
     fn, args = case()

@@ -40,7 +40,7 @@ def test_scatter_cache_matches(clean_env):
     cfg = get_config("qwen2-0.5b", smoke=True)
     m = make_model(cfg)
     params = m.init(jax.random.key(0))
-    logits, caches = m.prefill(params, {"tokens": jnp.ones((2, 8), jnp.int32)},
+    logits, caches, _ = m.prefill(params, {"tokens": jnp.ones((2, 8), jnp.int32)},
                                cache_len=16)
     tok = jnp.argmax(logits, -1).astype(jnp.int32)
     pos = jnp.full((2,), 8, jnp.int32)
@@ -55,7 +55,7 @@ def test_mla_scatter_cache_matches(clean_env):
     cfg = get_config("deepseek-v3-671b", smoke=True)
     m = make_model(cfg)
     params = m.init(jax.random.key(0))
-    logits, caches = m.prefill(params, {"tokens": jnp.ones((2, 8), jnp.int32)},
+    logits, caches, _ = m.prefill(params, {"tokens": jnp.ones((2, 8), jnp.int32)},
                                cache_len=16)
     tok = jnp.argmax(logits, -1).astype(jnp.int32)
     pos = jnp.full((2,), 8, jnp.int32)
@@ -87,7 +87,7 @@ def test_kv_quant_decode_close(clean_env):
     m = make_model(cfg)
     params = m.init(jax.random.key(0))
     inp = {"tokens": (jnp.arange(16).reshape(2, 8) * 7) % cfg.vocab_size}
-    logits, caches = m.prefill(params, inp, cache_len=16)
+    logits, caches, _ = m.prefill(params, inp, cache_len=16)
     tok = jnp.argmax(logits, -1).astype(jnp.int32)
     pos = jnp.full((2,), 8, jnp.int32)
     d0, _ = m.decode(params, tok, caches, pos)
@@ -110,7 +110,7 @@ def test_window_slice_decode_matches(clean_env):
     m = make_model(cfg)
     params = m.init(jax.random.key(0))
     inp = {"tokens": jnp.arange(32).reshape(2, 16) % cfg.vocab_size}
-    logits, caches = m.prefill(params, inp, cache_len=64 + cfg.meta_tokens)
+    logits, caches, _ = m.prefill(params, inp, cache_len=64 + cfg.meta_tokens)
     tok = jnp.argmax(logits, -1).astype(jnp.int32)
     pos = jnp.full((2,), 16, jnp.int32)
     d0, _ = m.decode(params, tok, caches, pos)

@@ -163,8 +163,53 @@ def test_moe_gemm(e, c, d, f, dtype):
     u = _rand((e, d, f), dtype, 0.05)
     dn = _rand((e, f, d), dtype, 0.05)
     tol = 3e-2 if dtype == jnp.bfloat16 else 1e-4
-    _assert_close(moe_mlp(buf, g, u, dn, bc=8, bf=128),
-                  moe_mlp_ref(buf, g, u, dn), tol, tol)
+    _assert_close(moe_mlp(buf, g, u, dn), moe_mlp_ref(buf, g, u, dn), tol, tol)
+
+
+@pytest.mark.parametrize("c", [1, 16, 100, 1024, 8192])
+def test_moe_gemm_tiles_fit_vmem_at_deepseek_width(c):
+    """At DeepSeek-V3's d 7168 and expert width 2048 the rule's blocks fit
+    the 16 MiB scoped VMEM, the row tile covers the rows in as few tiles as
+    the budget allows, and rows pad to a multiple of it."""
+    from repro.kernels.branch_gemm.ops import VMEM_BUDGET
+    from repro.kernels.moe_gemm.ops import select_tiles, vmem_bytes
+    bc, bf = select_tiles(c, 7168, 2048)
+    assert bc % 8 == 0 and 2048 % bf == 0 and bf % 128 == 0
+    assert vmem_bytes(bc, bf, 7168, 2) <= VMEM_BUDGET
+    c8 = -(-c // 8) * 8
+    # no larger row tile fits at the narrowest column tile
+    assert bc >= c8 or vmem_bytes(bc + 8, 128, 7168, 2) > VMEM_BUDGET \
+        or -(-c8 // (bc + 8)) == -(-c8 // bc)
+    # the old rule (all of d, bf 256) does not fit
+    assert vmem_bytes(bc, 256, 7168, 2) > VMEM_BUDGET
+
+
+def test_moe_gemm_skips_dead_rows_and_records_its_fallback():
+    from repro.kernels.moe_gemm.ops import moe_mlp
+    from repro.kernels.moe_gemm.ref import moe_mlp_ref
+    from repro.runtime.guard import kernel_log
+    e, c, d, f = 4, 24, 128, 256
+    buf = _rand((e, c, d), jnp.float32, 0.1)
+    g, u = _rand((e, d, f), jnp.float32, 0.05), _rand((e, d, f), jnp.float32, 0.05)
+    dn = _rand((e, f, d), jnp.float32, 0.05)
+    rows = jnp.asarray([0, 24, 5, 0], jnp.int32)
+    buf = buf * (jnp.arange(c)[None, :, None] < rows[:, None, None])
+    want = moe_mlp_ref(buf, g, u, dn)
+    from repro.kernels.moe_gemm.kernel import moe_mlp_pallas
+    # one row tile (the rule's pick), and 3 of 8 rows each: dead experts,
+    # and dead tiles after an expert's live ones
+    for got in (moe_mlp(buf, g, u, dn, rows),
+                moe_mlp_pallas(buf, g, u, dn, rows, bc=8, bf=128)):
+        for ex, r in enumerate(rows.tolist()):  # live rows only are defined
+            _assert_close(got[ex, :r], want[ex, :r], 1e-4, 1e-4)
+    assert kernel_log().count("moe_gemm") == 0
+    # off the 128 lattice: the reference, recorded
+    _assert_close(moe_mlp(buf[..., :96], g[:, :96], u[:, :96], dn[..., :96]),
+                  moe_mlp_ref(buf[..., :96], g[:, :96], u[:, :96],
+                              dn[..., :96]), 1e-5, 1e-5)
+    assert kernel_log().count("moe_gemm") == 1
+    ev = [x for x in kernel_log().events if x.site == "moe_gemm"][-1]
+    assert ev.action == "pallas->ref" and "d=96" in ev.reason
 
 
 # ---------------------------------------------------------------- rmsnorm

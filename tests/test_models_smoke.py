@@ -40,7 +40,7 @@ def test_arch_smoke(arch):
     inputs = {k: v for k, v in batch.items() if k != "labels"}
     extra = cfg.frontend.n_tokens if cfg.family == "vlm" else 0
     cache_len = S + 8 + cfg.meta_tokens + extra
-    logits, caches = model.prefill(params, inputs, cache_len=cache_len)
+    logits, caches, _ = model.prefill(params, inputs, cache_len=cache_len)
     assert logits.shape == (B, cfg.vocab_size)
     assert bool(jnp.isfinite(logits).all()), f"{arch}: non-finite prefill logits"
 
@@ -65,7 +65,7 @@ def test_decode_matches_teacher_forcing(arch):
 
     # prefill on the first 4, then decode the rest one at a time
     cache_len = 16 + cfg.meta_tokens
-    logits, caches = model.prefill(params, {"tokens": toks[:, :4]},
+    logits, caches, _ = model.prefill(params, {"tokens": toks[:, :4]},
                                    cache_len=cache_len)
     np.testing.assert_allclose(np.asarray(logits[0]),
                                np.asarray(full_logits[0, 3]),

@@ -71,7 +71,7 @@ def test_aux_free_bias_steers_routing():
     x = jax.random.normal(jax.random.key(1), (64, 32), jnp.float32)
     _, idx0, _ = route(p["router"], x, cfg.moe)
     count0 = int((idx0 == 0).sum())
-    p["router"]["bias"] = p["router"]["bias"].at[0].add(10.0)
+    p["router"]["b"] = p["router"]["b"].at[0].add(10.0)
     _, idx1, _ = route(p["router"], x, cfg.moe)
     count1 = int((idx1 == 0).sum())
     assert count1 > count0

@@ -43,7 +43,7 @@ def test_engine_greedy_matches_manual_decode(small_model):
 
     # manual loop
     toks = jnp.asarray([prompt], jnp.int32)
-    logits, caches = model.prefill(params, {"tokens": toks},
+    logits, caches, _ = model.prefill(params, {"tokens": toks},
                                    cache_len=64 + cfg.meta_tokens)
     out = [int(jnp.argmax(logits[0]))]
     pos = len(prompt)
@@ -59,7 +59,7 @@ def test_eos_terminates(small_model):
     cfg, model, params = small_model
     engine = InferenceEngine(model, params, max_slots=1, max_len=64)
     # probe: first greedy token becomes the eos so the request ends at len 1
-    logits, _ = model.prefill(params, {"tokens": jnp.asarray([[1, 2, 3]], jnp.int32)},
+    logits, _, _ = model.prefill(params, {"tokens": jnp.asarray([[1, 2, 3]], jnp.int32)},
                               cache_len=64 + cfg.meta_tokens)
     eos = int(jnp.argmax(logits[0]))
     engine.submit(Request(rid=0, prompt=[1, 2, 3], max_tokens=32, eos_id=eos))
@@ -163,6 +163,33 @@ def test_sampler_modes():
     assert int(t[0]) in (1, 2)
     t = sample_token(logits, jax.random.key(0), temperature=1.0, top_p=0.5)
     assert int(t[0]) == 1
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_batch_sampler_draws_what_per_slot_sampling_draws(dtype):
+    """The engine's one jitted sampler over the batch gives each row the
+    token ``sample_token`` gives that row alone with ``fold_in(sub, i)``
+    and its temperature, and flags the rows that are not finite."""
+    from repro.serving.engine import _batch_sampler
+
+    logits = jax.random.normal(jax.random.key(3), (5, 97)).astype(dtype)
+    logits = logits.at[3, 7].set(jnp.nan)
+    temps = np.asarray([0.0, 0.7, 1.3, 0.0, 2.0], np.float32)
+    sample = _batch_sampler()
+    for seed in range(4):
+        rng = jax.random.key(seed)
+        want_rng, sub = jax.random.split(rng)
+        want = [int(sample_token(logits[i:i + 1],
+                                 jax.random.fold_in(sub, i),
+                                 float(temps[i]))[0]) for i in range(5)]
+        got_rng, tokens, finite = sample(rng, logits, temps, True)
+        assert (jax.random.key_data(got_rng)
+                == jax.random.key_data(want_rng)).all()
+        assert tokens.tolist() == want
+        assert finite.tolist() == [True, True, True, False, True]
+    rng = jax.random.key(0)
+    _, greedy, _ = sample(rng, logits, np.zeros(5, np.float32), False)
+    assert greedy.tolist() == jnp.argmax(logits, axis=-1).tolist()
 
 
 # =========================================================================
