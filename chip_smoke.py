@@ -122,7 +122,7 @@ def capture_phase(cfg, params, seed: int, hw) -> list[str]:
     check_close("capture vs op-by-op", logits,
                 seq[names.index("logits")], failures)
     del seq
-    ref, _ = jax.jit(Model(cfg).prefill)(params, inputs)
+    ref, _, _ = jax.jit(Model(cfg).prefill)(params, inputs)
     check_close("capture vs jit(model.prefill), last position",
                 logits[:, -1], ref, failures)
     if len(exe.degradations) or compiled.degradations or len(sess.guard_log):
@@ -139,18 +139,22 @@ def _paged_prefill(model, params, tokens, maxp: int, page_size: int):
     import jax
     import jax.numpy as jnp
 
+    from repro.kernels.paged_decode.ref import positions_to_pages
+    from repro.models.attention import pool_rows
+
     b = tokens.shape[0]
     prefill = jax.jit(functools.partial(model.prefill,
                                         cache_len=maxp * page_size))
-    logits, cache = prefill(params, {"tokens": tokens})
+    logits, cache, _ = prefill(params, {"tokens": tokens})
     pages = model.init_paged_caches(1 + b * maxp, page_size)
 
     def place(paged, dense):             # dense [L, B, maxp*ps, KVH, D]
-        blocks = dense.reshape(dense.shape[0], b * maxp, page_size,
-                               *dense.shape[3:])
+        blocks = positions_to_pages(dense, page_size)
+        blocks = blocks.reshape(dense.shape[0], b * maxp, *blocks.shape[3:])
         return paged.at[:, 1:].set(blocks.astype(paged.dtype))
 
-    pages = jax.tree_util.tree_map(place, pages, cache)
+    rows = [pool_rows(model.cfg, c) for c in cache]
+    pages = jax.tree_util.tree_map(place, pages, rows)
     bt = 1 + jnp.arange(b * maxp, dtype=jnp.int32).reshape(b, maxp)
     return logits, pages, bt
 
@@ -177,8 +181,8 @@ def teacher_forced(cfg, params, seed: int, page_size: int, failures) -> None:
         per_step = [logits]
         for i in range(TF_STEPS):
             pos = jnp.full((SLOTS,), TF_PROMPT + i, jnp.int32)
-            logits, pages = step(params, stream[:, TF_PROMPT + i], pages,
-                                 bt, pos)
+            logits, pages, _ = step(params, stream[:, TF_PROMPT + i],
+                                    pages, bt, pos)
             per_step.append(logits)
         runs[name] = [np.asarray(x, np.float32) for x in per_step]
     for i, (got, ref) in enumerate(zip(runs["kernels"], runs["reference"])):

@@ -35,6 +35,10 @@ that has served for an hour holds it.
   ``decode_step_ms.serve``) or ``capture.call``, its children's means, and
   the five longest with the collector's time inside them;
 - ``gc``: the window's collections, by generation;
+- ``pool_in_place``: for a serve cell, the run's decode ticks beside the
+  counter ``engine.decode.pool_in_place`` (ticks whose step handed back
+  its donated page pools in place), and the same for the window's ticks
+  from their ``pool_in_place`` attribute;
 - ``device_s_by_scope``: for a serve cell, device seconds of each operation
   of the paged decode step by its name and the ``jax.named_scope`` names in
   its ``op_name`` metadata (read from the compiled step's HLO).
@@ -61,7 +65,7 @@ import tempfile
 import time
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-SCOPES = ("pool_write", "kv_layout", "attention", "mlp")
+SCOPES = ("pool_write", "attention", "latent_attention", "mlp")
 _INSTRUCTION = re.compile(r"^%?([\w.\-]+) = ")    # an event named by its HLO
 
 
@@ -224,7 +228,7 @@ def _cell(args, keep_dir) -> dict:
         return summarize(trace_dir, host_spans, t_open, t_close)
 
     trace.summarize = keep_trace
-    cached = engine_mod._cached_paged_decode_fn
+    cached = engine_mod._donated_paged_decode_fn
 
     def keep_step(model):
         fn = cached(model)
@@ -238,7 +242,7 @@ def _cell(args, keep_dir) -> dict:
             return fn(*a)
         return step
 
-    engine_mod._cached_paged_decode_fn = keep_step
+    engine_mod._donated_paged_decode_fn = keep_step
     buf = io.StringIO()
     try:
         with contextlib.redirect_stdout(buf):
@@ -247,7 +251,7 @@ def _cell(args, keep_dir) -> dict:
                              "--trace", "1"])
     finally:
         trace.summarize = summarize
-        engine_mod._cached_paged_decode_fn = cached
+        engine_mod._donated_paged_decode_fn = cached
     lines = [x for x in buf.getvalue().splitlines() if x.startswith("{")]
     out = {"workload": args.workload, "seed": args.seed, "rc": rc,
            "result": json.loads(lines[-1]) if lines else None}
@@ -301,6 +305,17 @@ def _reduce(kept, tracing) -> dict:
                bench_decode_idle=dict(in_decode))
     spans = tracing.spans(int(t_open * 1e9), int(t_close * 1e9))
     gc_spans = [sp for sp in spans if sp.name == "python.gc"]
+    decodes = [sp for sp in tracing.spans() if sp.name == "engine.step"
+               and sp.attrs.get("kind") == "decode"]
+    if decodes:
+        in_window = [sp for sp in decodes if sp.t0 >= int(t_open * 1e9)
+                     and sp.t1 <= int(t_close * 1e9)]
+        out["pool_in_place"] = {
+            "decode_ticks": len(decodes),
+            "counter": tracing.counters().get("engine.decode.pool_in_place"),
+            "window_ticks": len(in_window),
+            "window_in_place": sum(bool(sp.attrs.get("pool_in_place"))
+                                   for sp in in_window)}
     for key, parents in (
             ("decode_step", [sp for sp in spans if sp.name == "engine.step"
                              and sp.attrs.get("kind") == "decode"]),
@@ -357,7 +372,7 @@ def main(argv=None) -> int:
         json.dump(out, f, indent=1)
     print(json.dumps({k: out.get(k) for k in (
         "workload", "rc", "error", "idle_by_repro_span", "bench_decode_idle",
-        "decode_step", "capture_call", "gc")}), flush=True)
+        "decode_step", "capture_call", "gc", "pool_in_place")}), flush=True)
     return 0 if out["rc"] == 0 else 1
 
 

@@ -533,18 +533,20 @@ def mla_decode(p, x, cache, pos, cfg: ModelConfig, use_kernels=False):
 
 # -- paged decode (block-table KV) --------------------------------------------
 
-def gqa_paged_decode(p, x, pages, block_tables, pos, cfg: ModelConfig,
+def gqa_paged_decode(p, x, pool, layer, block_tables, pos, cfg: ModelConfig,
                      window=None, use_kernels=False):
-    """One-token decode against paged KV. x: [B,1,d]; pages: (k,v)
-    [P,ps,KVH,D]; block_tables: [B,MAXP] int32; pos: [B] int.
+    """One-token decode against paged KV. x: [B,1,d]; pool: (k,v) the whole
+    stack's [L,P,KVH,D,ps]; layer: this layer's index in the stack;
+    block_tables: [B,MAXP] int32; pos: [B] int.
 
-    Writes the new K/V at ``(table[pos//ps], pos%ps)`` and attends positions
-    ``[max(0, pos-window+1), pos]`` through the block table — there is no
-    per-sequence dense slab.  ``window`` may be the traced sentinel
-    (>= 2^29 disables): the start clamp maps it to 0.
+    Writes the new K/V in place at ``(layer, table[pos//ps], :, :, pos%ps)`` and
+    attends positions ``[max(0, pos-window+1), pos]`` through the block
+    table — there is no per-sequence dense slab, and no layer's pool is
+    sliced out of the stack.  ``window`` may be the traced sentinel (>= 2^29
+    disables): the start clamp maps it to 0.
     """
-    k_pages, v_pages = pages
-    ps = k_pages.shape[1]
+    k_pool, v_pool = pool
+    ps = k_pool.shape[4]
     b = x.shape[0]
     h, kvh, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     q = linear(p["wq"], x).reshape(b, 1, h, hd)
@@ -557,8 +559,8 @@ def gqa_paged_decode(p, x, pages, block_tables, pos, cfg: ModelConfig,
     page = block_tables[rows, pos // ps]                # [B] physical pages
     off = pos % ps
     with jax.named_scope("pool_write"):
-        k_pages = k_pages.at[page, off].set(k[:, 0].astype(k_pages.dtype))
-        v_pages = v_pages.at[page, off].set(v[:, 0].astype(v_pages.dtype))
+        k_pool = write_positions(k_pool, k[:, 0], page, off, layer)
+        v_pool = write_positions(v_pool, v[:, 0], page, off, layer)
     lengths = (pos + 1).astype(jnp.int32)
     starts = None
     if window is not None:
@@ -566,23 +568,26 @@ def gqa_paged_decode(p, x, pages, block_tables, pos, cfg: ModelConfig,
     with jax.named_scope("attention"):
         if use_kernels:
             from ..kernels.paged_decode.ops import paged_decode_attention
-            out = paged_decode_attention(q[:, 0], k_pages, v_pages,
-                                         block_tables, lengths, starts)
+            out = paged_decode_attention(q[:, 0], k_pool, v_pool,
+                                         block_tables, lengths, starts,
+                                         layer=layer)
         else:
             from ..kernels.paged_decode.ref import paged_decode_attention_ref
-            out = paged_decode_attention_ref(q[:, 0], k_pages, v_pages,
-                                             block_tables, lengths, starts)
+            out = paged_decode_attention_ref(q[:, 0], k_pool, v_pool,
+                                             block_tables, lengths, starts,
+                                             layer=layer)
     y = linear(p["wo"], out.reshape(b, 1, h * hd))
-    return y, (k_pages, v_pages)
+    return y, (k_pool, v_pool)
 
 
-def mla_paged_decode(p, x, pages, block_tables, pos, cfg: ModelConfig,
+def mla_paged_decode(p, x, pool, layer, block_tables, pos, cfg: ModelConfig,
                      use_kernels=False):
-    """Paged MLA decode over latent pages (ckv [P,ps,rank], kpe [P,ps,rope])
-    via matrix absorption — see :func:`mla_attention` for the math."""
+    """Paged MLA decode over the stack's latent pool ``([L,P,1,rank+rope,ps],)``
+    (each position ``[ckv ‖ kpe]``) via matrix absorption — see
+    :func:`mla_attention` for the math."""
     m = cfg.mla
-    ckv_pages, kpe_pages = pages
-    ps = ckv_pages.shape[1]
+    (lat_pool,) = pool
+    ps = lat_pool.shape[4]
     b = x.shape[0]
     h, rank = cfg.n_heads, m.kv_lora_rank
     q_nope, q_rope, c_new, r_new = _mla_qkv(p, x, cfg, pos[:, None])
@@ -590,10 +595,8 @@ def mla_paged_decode(p, x, pages, block_tables, pos, cfg: ModelConfig,
     page = block_tables[rows, pos // ps]
     off = pos % ps
     with jax.named_scope("pool_write"):
-        ckv_pages = ckv_pages.at[page, off].set(
-            c_new[:, 0].astype(ckv_pages.dtype))
-        kpe_pages = kpe_pages.at[page, off].set(
-            r_new[:, 0].astype(kpe_pages.dtype))
+        row = jnp.concatenate([c_new[:, 0], r_new[:, 0]], axis=-1)
+        lat_pool = write_positions(lat_pool, row[:, None], page, off, layer)
     lengths = (pos + 1).astype(jnp.int32)
     scale = mla_softmax_scale(cfg)
     with jax.named_scope("latent_attention"):
@@ -601,44 +604,81 @@ def mla_paged_decode(p, x, pages, block_tables, pos, cfg: ModelConfig,
         if use_kernels:
             from ..kernels.paged_decode.ops import paged_mla_decode_attention
             lat = paged_mla_decode_attention(q_nope[:, 0], q_rope[:, 0],
-                                             ckv_pages, kpe_pages, wk_b,
-                                             block_tables, lengths, scale)
+                                             lat_pool, wk_b, block_tables,
+                                             lengths, scale, layer)
         else:
             from ..kernels.paged_decode.ref import paged_decode_attention_ref
             q_lat = jnp.einsum("bhd,rhd->bhr", q_nope[:, 0], wk_b,
                                preferred_element_type=jnp.float32).astype(x.dtype)
             q_cat = jnp.concatenate([q_lat, q_rope[:, 0]], axis=-1)
-            k_cat = jnp.concatenate([ckv_pages, kpe_pages], axis=-1)[:, :, None, :]
-            lat = paged_decode_attention_ref(q_cat, k_cat,
-                                             ckv_pages[:, :, None, :],
-                                             block_tables, lengths, None, scale)
+            lat = paged_decode_attention_ref(q_cat, lat_pool, None,
+                                             block_tables, lengths, None,
+                                             scale, layer, rank)
         wv_b = p["wv_b"]["w"].reshape(rank, h, m.v_head_dim)
         out = jnp.einsum("bhr,rhd->bhd", lat, wv_b,
                          preferred_element_type=jnp.float32).astype(x.dtype)
     y = linear(p["wo"], out.reshape(b, 1, h * m.v_head_dim))
-    return y, (ckv_pages, kpe_pages)
+    return y, (lat_pool,)
 
 
-def attn_paged_decode(p, x, pages, block_tables, pos, cfg, window=None,
+def attn_paged_decode(p, x, pool, layer, block_tables, pos, cfg, window=None,
                       use_kernels=False):
     if cfg.mla is not None:
-        return mla_paged_decode(p, x, pages, block_tables, pos, cfg, use_kernels)
-    return gqa_paged_decode(p, x, pages, block_tables, pos, cfg, window,
+        return mla_paged_decode(p, x, pool, layer, block_tables, pos, cfg,
+                                use_kernels)
+    return gqa_paged_decode(p, x, pool, layer, block_tables, pos, cfg, window,
                             use_kernels)
 
 
-def init_paged_cache(cfg: ModelConfig, num_pages: int, page_size: int, dtype=None):
-    """Single-layer paged KV pages (page 0 reserved as the null page).
+def init_paged_cache(cfg: ModelConfig, num_layers: int, num_pages: int,
+                     page_size: int, dtype=None):
+    """One layer stack's page pool in the kernel's layout (page 0 reserved
+    as the null page), positions on the minor axis: GQA/MHA ``(k, v)``, each
+    [L, P, KVH, D, ps]; MLA one latent pool ``([L, P, 1, rank + rope, ps],)``
+    of ``[ckv ‖ kpe]`` columns.
 
     MLA always pages the *compressed* latent cache (no int8 variant — the
-    engine gates ``kv_quant`` off the paged path)."""
+    engine gates ``kv_quant`` off the paged path).  Materialized with
+    ``jnp.zeros`` so ``nbytes`` honestly reports the paged footprint."""
     dtype = dtype or cfg.dtype
     if cfg.mla is not None:
         m = cfg.mla
-        return (jnp.zeros((num_pages, page_size, m.kv_lora_rank), dtype),
-                jnp.zeros((num_pages, page_size, m.qk_rope_head_dim), dtype))
-    return (jnp.zeros((num_pages, page_size, cfg.n_kv_heads, cfg.head_dim), dtype),
-            jnp.zeros((num_pages, page_size, cfg.n_kv_heads, cfg.head_dim), dtype))
+        return (jnp.zeros((num_layers, num_pages, 1,
+                           m.kv_lora_rank + m.qk_rope_head_dim, page_size),
+                          dtype),)
+    shape = (num_layers, num_pages, cfg.n_kv_heads, cfg.head_dim, page_size)
+    return (jnp.zeros(shape, dtype), jnp.zeros(shape, dtype))
+
+
+def write_positions(pool, rows, page, off, layer=0):
+    """``rows[b]`` ([KVH, D], or [L', KVH, D] for layers ``layer ..``) into
+    position ``off[b]`` of page ``page[b]`` of a pool [L, P, KVH, D, ps]:
+    one ``dynamic_update_slice`` a row, which XLA does in place whatever
+    layout the pool has on the device (a scatter would relayout it)."""
+    for b in range(rows.shape[0]):
+        upd = rows[b].reshape(-1, 1, *pool.shape[2:4], 1).astype(pool.dtype)
+        pool = jax.lax.dynamic_update_slice(
+            pool, upd, (layer, page[b], 0, 0, off[b]))
+    return pool
+
+
+def pool_rows(cfg: ModelConfig, cache):
+    """A stack's dense cache (leaves [L, B, T, ...], as prefill and dense
+    decode keep it) as rows of its page pool's leaves: [L, B, T, KVH, D]
+    (MLA: one [L, B, T, 1, rank + rope] of ``[ckv ‖ kpe]``)."""
+    if cfg.mla is not None:
+        c, r = cache
+        return (jnp.concatenate([c, r.astype(c.dtype)], -1)[..., None, :],)
+    return cache
+
+
+def dense_cache(cfg: ModelConfig, rows):
+    """The inverse of :func:`pool_rows`."""
+    if cfg.mla is not None:
+        (lat,) = rows
+        return (lat[..., 0, :cfg.mla.kv_lora_rank],
+                lat[..., 0, cfg.mla.kv_lora_rank:])
+    return rows
 
 
 # -- dispatch -----------------------------------------------------------------

@@ -444,12 +444,15 @@ def init_decode_caches(cfg: ModelConfig, batch: int, length: int):
     return caches
 
 
-def block_step_paged(p, x, pages, block_tables, pos, cfg: ModelConfig, window,
-                     layer_kind: str = "dense", use_kernels: bool = False):
-    """Single-token decode against paged KV. x: [B,1,d]; pages per layer."""
+def block_step_paged(p, x, pool, layer, block_tables, pos, cfg: ModelConfig,
+                     window, layer_kind: str = "dense",
+                     use_kernels: bool = False):
+    """Single-token decode against paged KV. x: [B,1,d]; pool: the whole
+    stack's page pool, of which this block is layer ``layer``."""
     h = apply_norm(p["norm1"], x, cfg.norm)
-    attn_out, new_pages = attn_paged_decode(p["attn"], h, pages, block_tables,
-                                            pos, cfg, window, use_kernels)
+    attn_out, pool = attn_paged_decode(p["attn"], h, pool, layer,
+                                       block_tables, pos, cfg, window,
+                                       use_kernels)
     x = x + attn_out * cfg.residual_scale
     h2 = apply_norm(p["norm2"], x, cfg.norm)
     aux = {}
@@ -460,39 +463,38 @@ def block_step_paged(p, x, pages, block_tables, pos, cfg: ModelConfig, window,
         else:
             f_out, aux = ffn(p["ffn"], h2, cfg, None, use_kernels)
     x = x + f_out * cfg.residual_scale
-    return x, new_pages, {k: aux[k] for k in ("moe_held",) if k in aux}
+    return x, pool, {k: aux[k] for k in ("moe_held",) if k in aux}
 
 
-def _scan_step_paged(stack_params, kind, windows, x, caches, block_tables, pos,
+def _scan_step_paged(stack_params, kind, windows, x, pool, block_tables, pos,
                      cfg, use_kernels=False):
-    """Returns (x, caches, counters summed over the stack)."""
+    """Returns (x, pool, counters summed over the stack).  The scan carries
+    the stack's page pool and each layer writes its row into it in place:
+    nothing maps over the pool, so nothing copies it."""
     win_arr = jnp.array([w if w > 0 else (1 << 30) for w in windows], jnp.int32)
+    layers = jnp.arange(len(windows), dtype=jnp.int32)
 
     def body(carry, xs):
-        x, aux = carry
-        p_l, win_l, cache_l = xs
-        x, new_cache, a = block_step_paged(p_l, x, cache_l, block_tables, pos,
-                                           cfg, win_l, kind, use_kernels)
-        return (x, _add_aux(aux, a)), new_cache
+        x, pool, aux = carry
+        p_l, win_l, layer = xs
+        x, pool, a = block_step_paged(p_l, x, pool, layer, block_tables, pos,
+                                      cfg, win_l, kind, use_kernels)
+        return (x, pool, _add_aux(aux, a)), None
 
-    (x, aux), new_caches = jax.lax.scan(body, (x, _aux0(kind, cfg)),
-                                        (stack_params, win_arr, caches))
-    return x, new_caches, aux
+    (x, pool, aux), _ = jax.lax.scan(body, (x, pool, _aux0(kind, cfg)),
+                                     (stack_params, win_arr, layers))
+    return x, pool, aux
 
 
 def init_paged_decode_caches(cfg: ModelConfig, num_pages: int, page_size: int):
-    """Paged KV leaves [L, P, ps, ...] per stack.  Materialized with
-    ``jnp.zeros`` (not broadcast) so ``nbytes`` honestly reports the paged
-    footprint the serving bench compares against the dense slab."""
+    """One page pool per layer stack, in the paged kernel's layout (see
+    :func:`~repro.models.attention.init_paged_cache`)."""
     if cfg.family in ("ssm", "hybrid"):
         raise ValueError(
             f"family {cfg.family!r} carries recurrent state; paged KV "
             "applies only to pure-attention stacks")
-    caches = []
-    for kind, n, _ in stack_meta(cfg):
-        kv = init_paged_cache(cfg, num_pages, page_size)
-        caches.append(tuple(jnp.zeros((n,) + x.shape, x.dtype) for x in kv))
-    return caches
+    return [init_paged_cache(cfg, n, num_pages, page_size)
+            for _, n, _ in stack_meta(cfg)]
 
 
 def lm_paged_decode(params, token, caches, block_tables, pos,

@@ -40,6 +40,7 @@ in its own ``guard_log``.
 from __future__ import annotations
 
 import copy
+import functools
 import time
 import warnings
 import weakref
@@ -50,7 +51,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..configs.base import ModelConfig
+from ..kernels.paged_decode.ref import page_positions, positions_to_pages
 from ..models import Model
+from ..models.attention import dense_cache, pool_rows, write_positions
 from ..runtime import tracing
 from ..runtime.faults import FaultInjected, FaultPlan
 from ..runtime.faults import get_active as _active_faults
@@ -116,6 +119,55 @@ def _cached_paged_decode_fn(model: Model):
         fn = jax.jit(_step)
         _PAGED_JIT_CACHE[model] = fn
     return fn
+
+
+def _donated_paged_decode_fn(model: Model):
+    """The engine's paged decode step: :func:`_cached_paged_decode_fn`
+    jitted once more with its page pools (argument 1) donated, so each
+    layer writes its row into them in place and the step hands the same
+    buffers back.  The donation wraps the cached step rather than living in
+    it, so a step that returns the pools it was given hands back live
+    buffers, not deleted ones.  Not cached: jit wrappers of one cached step
+    share its traces and executables."""
+    return jax.jit(_cached_paged_decode_fn(model), donate_argnums=1)
+
+
+# The pool's other writers, each one jitted update of the donated pools in
+# place (an eager ``.at[].set`` would build a second pool beside the first).
+
+@functools.partial(jax.jit, donate_argnums=0, static_argnums=3)
+def _write_pages(caches, rows, pages, first: int):
+    """Whole pages ``first .. first + len(pages)`` of a batch-1 dense cache,
+    as :func:`pool_rows` gives it (leaves [L, 1, T, KVH, D]), into the pool
+    pages ``pages`` of every layer."""
+    n = pages.shape[0]
+
+    def write(pool, leaf):
+        ps = pool.shape[4]
+        x = positions_to_pages(leaf[:, 0, first * ps:(first + n) * ps], ps)
+        return pool.at[:, pages].set(x.astype(pool.dtype))
+
+    return jax.tree_util.tree_map(write, caches, rows)
+
+
+@functools.partial(jax.jit, donate_argnums=0)
+def _write_rows(caches, rows, pages, offs):
+    """One position per row: ``rows`` leaves [L, R, KVH, D] at
+    ``(pages[r], offs[r])`` of every layer."""
+    return jax.tree_util.tree_map(
+        lambda pool, r: write_positions(pool, jnp.moveaxis(r, 1, 0), pages,
+                                        offs), caches, rows)
+
+
+@functools.partial(jax.jit, donate_argnums=0)
+def _copy_page(caches, dst, src):
+    """Page ``src`` duplicated into page ``dst`` across every layer."""
+    return jax.tree_util.tree_map(
+        lambda pool: pool.at[:, dst].set(pool[:, src]), caches)
+
+
+def _buffer_ptrs(tree) -> list[int]:
+    return [x.unsafe_buffer_pointer() for x in jax.tree_util.tree_leaves(tree)]
 
 
 def _batch_sampler():
@@ -243,7 +295,7 @@ class InferenceEngine:
                 num_pages = 1 + max_slots * self._pages_per_req
             self.pool = KVPagePool(KVPoolConfig(num_pages, page_size))
             self.caches = model.init_paged_caches(num_pages, page_size)
-            self._paged_decode = _cached_paged_decode_fn(model)
+            self._paged_decode = _donated_paged_decode_fn(model)
             self._page_bounces: dict[str, int] = {}
         else:
             from ..models.transformer import init_decode_caches
@@ -251,7 +303,7 @@ class InferenceEngine:
             self.caches = init_decode_caches(self.cfg, max_slots, cache_len)
         self._decode = _cached_decode_fn(model)
         self._sample = _batch_sampler()
-        self._moe_held = {}          # the last decode tick's expert counts
+        self._step_attrs = {}        # the last decode tick's span attributes
         # Measured-mode Opara schedule of this engine's step graph, filled by
         # calibrate_schedule().  Engines for the same (model structure, batch
         # geometry, hardware) share one measured profile via the core
@@ -531,11 +583,11 @@ class InferenceEngine:
             if not free and len(self.admission) \
                     and self.admission_cfg.preemption:
                 out.extend(self._maybe_preempt())
-            self._moe_held = {}
+            self._step_attrs = {}
             out.extend(self._paged_decode_tick() if self.paged
                        else self._decode_tick())
-            if self._moe_held:
-                sp.set(**self._moe_held)
+            if self._step_attrs:
+                sp.set(**self._step_attrs)
         return out
 
     def _work_pending(self) -> bool:
@@ -768,9 +820,8 @@ class InferenceEngine:
             if faults is not None:
                 faults.fire("block_table_build")
             bt = jnp.asarray(self._block_table_array())
-            (logits, _), caches = self._paged_decode(
+            (logits, _), self.caches = self._paged_decode(
                 self.params, self.caches, token, bt, pos)
-            self.caches = caches
         except Exception as exc:
             logits = self._paged_fallback(exc)
             if logits is None:
@@ -817,27 +868,22 @@ class InferenceEngine:
 
     def _scatter_pages(self, req: Request, cache, n_pos: int,
                        skip_pages: int = 0) -> None:
-        """Scatter a batch-1 dense prefill cache into this request's pages
+        """Write a batch-1 dense prefill cache (page-aligned, zeros past
+        the prompt) into this request's pages, whole pages at a time
         (skipping pages adopted via prefix sharing — already resident)."""
-        ps = self.pool.page_size
-        table = np.asarray(self.pool.table(req.rid), np.int32)
-        positions = np.arange(skip_pages * ps, n_pos)
-        if positions.size == 0:
+        table = self.pool.table(req.rid)
+        n = -(-n_pos // self.pool.page_size)
+        if n <= skip_pages:
             return
-        pages = table[positions // ps]
-        offs = positions % ps
-
-        def scat(paged_leaf, dense_leaf):
-            return paged_leaf.at[:, pages, offs].set(
-                dense_leaf[:, 0, positions].astype(paged_leaf.dtype))
-
-        self.caches = jax.tree_util.tree_map(scat, self.caches, cache)
+        rows = [pool_rows(self.cfg, c) for c in cache]
+        self.caches = _write_pages(
+            self.caches, rows, jnp.asarray(table[skip_pages:n], jnp.int32),
+            skip_pages)
 
     def _copy_page(self, dst: int, src: int) -> None:
         """Copy-on-write materialization: duplicate page ``src`` into the
         freshly allocated ``dst`` across every layer's leaves."""
-        self.caches = jax.tree_util.tree_map(
-            lambda leaf: leaf.at[:, dst].set(leaf[:, src]), self.caches)
+        self.caches = _copy_page(self.caches, np.int32(dst), np.int32(src))
 
     def _block_table_array(self) -> np.ndarray:
         """[max_slots, pages_per_req] int32; unused entries point at the
@@ -857,7 +903,7 @@ class InferenceEngine:
         out: list[Request] = []
         faults = self._faults()
         still = []
-        err = None
+        err = ptrs = None
         with tracing.span("engine.decode.prepare"):
             for i in active:
                 req = self.slots[i]
@@ -883,6 +929,8 @@ class InferenceEngine:
             if still:
                 token = jnp.asarray(self.last_token)
                 pos = jnp.asarray(self.pos)
+                if tracing.enabled():       # the pools the step will donate
+                    ptrs = _buffer_ptrs(self.caches)
                 try:
                     if faults is not None:
                         faults.fire("block_table_build")
@@ -894,11 +942,12 @@ class InferenceEngine:
         if err is None:
             try:
                 with tracing.span("engine.decode.dispatch"):
-                    (logits, counters), caches = self._paged_decode(
+                    # rebound before anything can raise: the step donated
+                    # the pools it was given
+                    (logits, counters), self.caches = self._paged_decode(
                         self.params, self.caches, token, bt, pos)
                     if faults is not None:
                         logits = faults.fire("decode_step", payload=logits)
-                self.caches = caches
             except Exception as exc:
                 err = exc
         if err is not None:
@@ -912,6 +961,12 @@ class InferenceEngine:
                         req, f"paged decode failed on both rungs: {err!r}"))
                 return out
         out.extend(self._advance_slots(still, logits, counters))
+        if err is None and ptrs is not None:
+            # read after the tick's host read of its tokens: the step is done
+            in_place = _buffer_ptrs(self.caches) == ptrs
+            if in_place:
+                tracing.count("engine.decode.pool_in_place")
+            self._step_attrs["pool_in_place"] = in_place
         return out
 
     def _paged_fallback(self, exc: Exception):
@@ -934,18 +989,15 @@ class InferenceEngine:
 
     def _dense_gather_decode(self):
         """Gather every slot's pages into a dense [L,B,T,...] slab, run the
-        eager dense decode, scatter ONLY the newly written position back
+        eager dense decode, write ONLY the newly written position back
         into the pages.  Built without firing fault sites — the rescue rung
         must not re-inject."""
         bt_np = self._block_table_array()
         bt = jnp.asarray(bt_np)
-        maxp, ps = self._pages_per_req, self.pool.page_size
-
-        def gather(leaf):
-            g = leaf[:, bt]                      # [L, B, MAXP, ps, ...]
-            return g.reshape(g.shape[0], g.shape[1], maxp * ps, *g.shape[4:])
-
-        dense = jax.tree_util.tree_map(gather, self.caches)
+        ps = self.pool.page_size
+        dense = [dense_cache(self.cfg, tuple(page_positions(pool[:, bt])
+                                             for pool in stack))
+                 for stack in self.caches]
         logits, new_dense = self.model.decode(
             self.params, jnp.asarray(self.last_token), dense,
             jnp.asarray(self.pos))
@@ -953,15 +1005,12 @@ class InferenceEngine:
         if rows:
             wp = np.array([int(self.pos[i]) + self.cfg.meta_tokens
                            for i in rows], np.int32)
-            pages = bt_np[rows, wp // ps]
-            offs = wp % ps
             rows_a = np.array(rows, np.int32)
-
-            def scat(paged_leaf, dense_leaf):
-                return paged_leaf.at[:, pages, offs].set(
-                    dense_leaf[:, rows_a, wp].astype(paged_leaf.dtype))
-
-            self.caches = jax.tree_util.tree_map(scat, self.caches, new_dense)
+            new_rows = [tuple(leaf[:, rows_a, wp]
+                              for leaf in pool_rows(self.cfg, stack))
+                        for stack in new_dense]
+            self.caches = _write_rows(self.caches, new_rows,
+                                      bt_np[rows_a, wp // ps], wp % ps)
         return logits
 
     def _decode_tick(self) -> list[Request]:
@@ -1047,7 +1096,7 @@ class InferenceEngine:
         with tracing.span("engine.decode.wait"):
             self.rng, tokens, finite = self._sample(
                 self.rng, logits, temps, bool(temps.any()))
-            (tokens, finite_rows), self._moe_held = _read(
+            (tokens, finite_rows), self._step_attrs = _read(
                 (tokens, finite), counters)
         with tracing.span("engine.decode.sample"):
             finished: list[Request] = []

@@ -1,8 +1,8 @@
 """Deterministic on-device KV page pool: fixed pages, ref counts, prefix COW.
 
 The pool manages *page identities only* — the tensors live in the engine's
-paged cache leaves ``[L, P, ps, ...]``; the pool decides which physical page
-each logical page of each request maps to.  Invariants:
+page pools ``[L, P, ..., ps]`` (pages on axis 1); the pool decides which
+physical page each logical page of each request maps to.  Invariants:
 
 * Page 0 is the reserved **null page**: never allocated, never freed; block
   tables of inactive slots (and positions past a request's length) point at

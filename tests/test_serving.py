@@ -1,4 +1,6 @@
 """Serving engine: completion, continuous batching, greedy consistency."""
+import time
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -411,3 +413,86 @@ def test_serve_paged_returns_fallback_ledger():
     assert res["fault_stats"]["paged_decode_fallbacks"] == 0
     assert res["fault_stats"]["failed_requests"] == 0
     assert res["degradations"] == []
+
+
+# tiny MHA, GQA and MLA stacks: the page pool layouts the engine serves.
+# The MLA stack takes dense FFNs: a dense engine's re-prefill on resume
+# routes an MoE layer's tokens under another capacity than decode did.
+POOL_LAYOUTS = [("minicpm-2b", 4, False), ("qwen2-0.5b", 4, False),
+                ("deepseek-v3-671b", 4, False), ("llama3.2-1b", 128, True)]
+
+
+@pytest.fixture(scope="module", params=POOL_LAYOUTS,
+                ids=lambda p: f"{p[0]}-ps{p[1]}" + ("-kernels" if p[2] else ""))
+def pool_model(request):
+    import dataclasses
+
+    arch, page_size, kernels = request.param
+    cfg = get_config(arch, smoke=True)
+    if cfg.mla is not None:
+        cfg = dataclasses.replace(cfg, family="dense", moe=None)
+    params = make_model(cfg).init(jax.random.key(0))
+    return make_model(cfg, use_kernels=kernels), params, page_size
+
+
+def _admit_cow_preempt_resume(model, params, paged, page_size, plan=None):
+    """Two requests on one prompt (prefix pages shared, copied on write),
+    then a deadline-critical one that preempts the lower-priority of them,
+    which resumes from its pages."""
+    from repro.runtime.faults import FaultPlan
+    from repro.serving import AdmissionConfig
+
+    engine = InferenceEngine(
+        model, params, max_slots=2, max_len=40, seed=5,
+        admission=AdmissionConfig(policy="edf", preemption=True),
+        paged_kv=paged, page_size=page_size, prefix_sharing=paged,
+        num_pages=1 + 3 * -(-40 // page_size),   # a preempted slot's too
+        fault_plan=FaultPlan.parse(plan) if plan else None)
+    prompt = [3, 1, 4, 1, 5, 9, 2]
+    engine.submit(Request(rid="a", prompt=list(prompt), max_tokens=10,
+                          priority=1))
+    engine.submit(Request(rid="b", prompt=list(prompt), max_tokens=10))
+    for _ in range(5):
+        engine.step()
+    engine.submit(Request(rid="hi", prompt=[9, 9, 2], max_tokens=3,
+                          priority=3, ttl=8))
+    done = engine.run(200)
+    return engine, _terminal_map(done)
+
+
+def test_paged_pool_serves_the_dense_tokens(pool_model):
+    """Across admissions, a copy-on-write page and a preemption's resume,
+    the paged engine serves the dense engine's greedy tokens, and every
+    decode tick hands its donated pools back in place."""
+    from repro.runtime import tracing
+
+    model, params, page_size = pool_model
+    _, dense = _admit_cow_preempt_resume(model, params, False, page_size)
+    t0 = time.perf_counter_ns()
+    before = tracing.counters().get("engine.decode.pool_in_place", 0)
+    engine, paged = _admit_cow_preempt_resume(model, params, True, page_size)
+    assert paged == dense
+    assert all(s[0] is RequestState.DONE for s in paged.values())
+    assert engine.fault_stats["page_resumes"] == 1
+    assert engine.fault_stats["paged_decode_fallbacks"] == 0
+    if page_size < 40:                   # a partial shared page to copy
+        assert engine.pool.stats["cow_copies"] >= 1
+    ticks = [s for s in tracing.spans(since_ns=t0) if s.name == "engine.step"
+             and s.attrs.get("kind") == "decode"]
+    assert ticks and all(s.attrs["pool_in_place"] for s in ticks)
+    counted = tracing.counters()["engine.decode.pool_in_place"] - before
+    assert counted == len(ticks)
+
+
+def test_decode_step_fault_recovers_through_dense_gather(pool_model):
+    """An injected fault after the donating step has run: the engine holds
+    the pools the step handed back, and the dense-gather rung serves the
+    same tokens from them."""
+    model, params, page_size = pool_model
+    _, clean = _admit_cow_preempt_resume(model, params, True, page_size)
+    with pytest.warns(DegradationWarning, match="dense-gather"):
+        engine, faulty = _admit_cow_preempt_resume(
+            model, params, True, page_size, plan="decode_step:raise:2")
+    assert faulty == clean
+    assert engine.fault_stats["paged_decode_fallbacks"] == 2
+    assert engine.fault_stats["block_table_faults"] == 2

@@ -227,7 +227,7 @@ def test_gc_collections_are_spans():
 
 def test_named_scopes_reach_the_lowered_programs(small_model):
     cfg, model, params = small_model
-    # the Pallas route (page size a lane multiple) transposes the pages
+    # the Pallas route (page size a lane multiple) reads the pool in place
     engine = InferenceEngine(make_model(cfg, use_kernels=True), params,
                              max_slots=2, max_len=64, paged_kv=True,
                              page_size=128)
@@ -235,7 +235,8 @@ def test_named_scopes_reach_the_lowered_programs(small_model):
     bt = jnp.zeros((2, engine._pages_per_req), jnp.int32)
     scopes = _scopes(engine._paged_decode.lower(
         params, engine.caches, z, bt, z))
-    assert {"pool_write", "attention", "kv_layout", "mlp"} <= scopes
+    assert {"pool_write", "attention", "mlp"} <= scopes
+    assert "kv_layout" not in scopes        # the pool is in the kernel's layout
     exe = Session().compile(build_inception_like(n_blocks=2, width=3))
     routes = {s.route for s in exe.executable.steps}
     assert routes and routes <= _scopes(
